@@ -1,7 +1,9 @@
 """Pod-scale look-ahead evidence: distributed schedule comparison.
 
-Runs in a subprocess with 8 virtual host devices (the only place outside
-``launch/dryrun.py`` that forces a device count).  Two lanes:
+Runs in a subprocess with 8 virtual CPU devices (the only place outside
+``launch/dryrun.py`` that forces a device count).  The child pins itself
+to the CPU (``JAX_PLATFORMS=cpu``): on a TPU host the parent may already
+hold the chip, and a second process must not reach for it.  Two lanes:
 
 * :func:`run` — the quick default-group lane: wall-clock of the
   block-cyclic LU wrapper with ``lookahead=True`` vs ``False`` (virtual
@@ -104,6 +106,7 @@ print("RESULT:" + json.dumps(rows))
 def _child(script: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=900)
     for line in proc.stdout.splitlines():

@@ -62,13 +62,13 @@ def run_kernels(n: int = 64, b: int = 16, gemm_n: int = 256):
     from repro.kernels import panels, ref
     from repro.tune.model import gemm_blocks
 
-    interp = kops._INTERPRET
     rows = []
 
     # --- BLIS five-loop GEMM, blocking sweep -------------------------------
     # gemm_n is larger than the panel n so the §9 targets produce *distinct*
     # blockings (at small n every target collapses to one aligned shape).
     a, bm_ = random_matrix(gemm_n, 0), random_matrix(gemm_n, 1)
+    interp = kops._interpret(a)
     flops = 2.0 * gemm_n ** 3
     kbs = [gemm_blocks(gemm_n, gemm_n, gemm_n, a.dtype)]
     for target in ((256, 256, 256), (128, 128, 128)):

@@ -118,15 +118,13 @@ def main(argv=None) -> None:
             ap.error(f"--only {args.only!r} matches no benchmark group "
                      f"(have: {', '.join(n for n, _ in _groups(args))})")
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows = []
     print(CSV_HEADER)
     for name, fn in groups:
-        try:
-            rows += fn()
-        except Exception as e:  # subprocess env issues shouldn't kill the run
-            if not name.startswith("distributed"):
-                raise
-            print(f"bench_{name} skipped: {e!r}", file=sys.stderr)
+        rows += fn()
     print(f"\n# {len(rows)} rows")
 
     if args.csv:

@@ -3,8 +3,9 @@
     PYTHONPATH=src python examples/factorize.py [--n 1024] [--b 192]
 
 Times MTB (fork–join) vs RTM (fragmented) vs LA (static look-ahead) for
-LU / QR / Cholesky on this machine's CPU backend and validates that all
-variants produce identical results (the paper's key numerics claim).
+LU / QR / Cholesky on JAX's default backend (a TPU where one is attached,
+else the CPU) and validates that all variants produce identical results
+(the paper's key numerics claim).
 
 Then drives the solve layer (DESIGN.md §8): gesv/posv round trips, QR least
 squares, the factor-once/solve-many amortization that motivates the
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.lookahead import get_variant
 from repro.solve import gehrd, gels, geqp3, gesv, lu_factor, posv
 
@@ -134,4 +136,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

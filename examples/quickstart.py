@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.core import lu as L
 from repro.core.lookahead import get_variant
@@ -45,5 +46,6 @@ def train_demo():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     factorize_demo()
     train_demo()

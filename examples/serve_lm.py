@@ -7,6 +7,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.models import api
 from repro.serve.engine import ServeConfig, ServeEngine
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.serve import ServerConfig, SolveServer
 
 
@@ -63,4 +64,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

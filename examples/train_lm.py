@@ -11,6 +11,7 @@ steps with checkpointing + fault-tolerance wired in.
 import argparse
 import dataclasses
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import SyntheticTask
 from repro.train.trainer import Trainer, TrainerConfig
@@ -62,4 +63,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

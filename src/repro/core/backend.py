@@ -39,6 +39,11 @@ _GEMM_KQ = 128
 _GEMM_MQ = 32
 _GEMM_NQ = 32
 
+#: Every f32 product runs at full f32 precision.  On a TPU a default-
+#: precision f32 dot takes bf16 passes, which would leave the factors with
+#: bf16-level error; on the CPU the setting changes no value.
+_PRECISION = lax.Precision.HIGHEST
+
 
 def _gemm_impl(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """C = A·B with f32 accumulation for bf16 inputs.
@@ -59,11 +64,21 @@ def _gemm_impl(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     sequentially — so the result depends only on the real values, never on
     how much zero padding or batching surrounds them.  This is what lets the
     serve layer promise padded+batched == unbatched bitwise.
+
+    Lowered for the CPU, N is also computed in ``_GEMM_NQ``-wide tiles.
+    XLA:CPU picks its dot algorithm (threading, K-splitting) by the *total*
+    N, so ``gemm(A, B)[:, j0:j1]`` and ``gemm(A, B[:, j0:j1])`` round
+    differently unless every dot has the same N.  Fixed-width column tiles
+    make the product column-decomposable, the property the mesh engine's
+    per-block trailing updates rely on (``core/distributed.py``).  Any other
+    platform takes the full-width dot, the one the MXU wants; the choice
+    follows the platform the GEMM is lowered for.
     """
     acc = _acc_dtype(a.dtype)
+    dot = functools.partial(jnp.matmul, preferred_element_type=acc,
+                            precision=_PRECISION)
     if a.ndim != 2 or b.ndim != 2:
-        out = jnp.matmul(a, b, preferred_element_type=acc)
-        return out.astype(a.dtype)
+        return dot(a, b).astype(a.dtype)
     m, k = a.shape
     n = b.shape[1]
     kp = max(_GEMM_KQ, -(-k // _GEMM_KQ) * _GEMM_KQ)
@@ -73,15 +88,26 @@ def _gemm_impl(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
         jnp.zeros((mp, kp), a.dtype).at[:m, :k].set(a))
     bp = b if (k == kp and n == np_) else (
         jnp.zeros((kp, np_), b.dtype).at[:k, :n].set(b))
-    if kp == _GEMM_KQ:
-        out = jnp.matmul(ap, bp, preferred_element_type=acc)
-    else:
+
+    def product(bt):
+        if kp == _GEMM_KQ:
+            return dot(ap, bt)
+
         def body(i, c):
             ac = lax.dynamic_slice_in_dim(ap, i * _GEMM_KQ, _GEMM_KQ, 1)
-            bc = lax.dynamic_slice_in_dim(bp, i * _GEMM_KQ, _GEMM_KQ, 0)
-            return c + jnp.matmul(ac, bc, preferred_element_type=acc)
-        out = lax.fori_loop(0, kp // _GEMM_KQ, body,
-                            jnp.zeros((mp, np_), acc))
+            bc = lax.dynamic_slice_in_dim(bt, i * _GEMM_KQ, _GEMM_KQ, 0)
+            return c + dot(ac, bc)
+        return lax.fori_loop(0, kp // _GEMM_KQ, body,
+                             jnp.zeros((mp, bt.shape[1]), acc))
+
+    def column_tiles(bt):
+        tiles = bt.reshape(kp, np_ // _GEMM_NQ, _GEMM_NQ).transpose(1, 0, 2)
+        return lax.map(product, tiles).transpose(1, 0, 2).reshape(mp, np_)
+
+    if np_ == _GEMM_NQ:
+        out = product(bp)
+    else:
+        out = lax.platform_dependent(bp, cpu=column_tiles, default=product)
     return out[:m, :n].astype(a.dtype)
 
 

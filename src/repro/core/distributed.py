@@ -47,8 +47,13 @@ broadcast with its owner shard and payload bytes, and
 ``report.overlap`` folds them into a broadcast-hidden fraction (structural,
 like overlap-efficiency: the CPU backend serializes, a real mesh overlaps).
 
-Runs today on CPU via ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-— the same code path on a real TPU mesh.
+The same code runs on a TPU mesh (``chip_smoke.py --chips 4`` drives
+``gesv``/``posv`` over four v5e chips) and on virtual CPU devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``, the tests).  The
+mesh may have ``Explicit`` axes (``jax.make_mesh``'s default) or ``Auto``
+ones.  Bitwise equality with the single-device engine is pinned on the CPU,
+where the canonical GEMM computes N in fixed-width tiles
+(``repro.core.backend._gemm_impl``).
 """
 from __future__ import annotations
 
@@ -58,30 +63,8 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# The replication/VMA checker mis-handles replicated values produced inside
-# the block-cyclic step functions below, so it stays disabled on every jax
-# version (numerics are unaffected).  The kwarg was renamed
-# check_rep -> check_vma when shard_map moved to the top level.
-try:
-    _shard_map_impl = jax.shard_map          # jax >= 0.5
-    _CHECK_KWARGS = ({"check_vma": False}, {"check_rep": False}, {})
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _CHECK_KWARGS = ({"check_rep": False},)
-
-
-def _shard_map(*args, **kwargs):
-    for extra in _CHECK_KWARGS:
-        try:
-            return _shard_map_impl(*args, **extra, **kwargs)
-        except TypeError:
-            continue
-    return _shard_map_impl(*args, **kwargs)
 
 from repro.core.backend import Backend, JNP_BACKEND
 from repro.core.blocking import BlockSpec, PanelStep, normalize_block, panel_steps
@@ -160,40 +143,46 @@ def _padded_len(n: int, nd: int, b: int) -> int:
     return _ceil_div(_ceil_div(n, b), nd) * nd * b
 
 
-def _cyclic_perm(n: int, nd: int, b: int) -> np.ndarray:
-    nblocks = n // b
-    perm = []
-    for p in range(nd):
-        for lj in range(nblocks // nd):
-            g = lj * nd + p
-            perm.extend(range(g * b, (g + 1) * b))
-    return np.asarray(perm)
+def _replicated(x):
+    """``x`` gathered onto every device of its mesh, if it is sharded there.
+
+    The conversions below are pure reshapes and transposes; on a mesh with
+    ``AxisType.Explicit`` axes (what ``jax.make_mesh`` builds by default)
+    merging a sharded dimension needs an output sharding, so a sharded
+    input is first made replicated.  Plain and traced arrays pass through.
+    """
+    sh = getattr(x, "sharding", None) if isinstance(x, jax.Array) \
+        and not isinstance(x, jax.core.Tracer) else None
+    if isinstance(sh, NamedSharding) and not sh.is_fully_replicated:
+        return jax.device_put(x, NamedSharding(sh.mesh, P()))
+    return x
 
 
 def to_block_cyclic(a: jnp.ndarray, nd: int, b: int) -> jnp.ndarray:
     """(m, n) → (nd, m, L): device-major column block-cyclic layout.
 
+    Column block ``j`` goes to device ``j % nd``, local slot ``j // nd``.
     Shapes with ``n`` not divisible by ``nd·b`` are zero-padded on the right
     up to whole per-device block rows (``L = ceil(ceil(n/b)/nd)·b``);
     :func:`from_block_cyclic` with ``n=`` recovers the original columns.
     """
+    a = _replicated(a)
     m, n = a.shape
     lp = _padded_len(n, nd, b)
     if lp != n:
         a = jnp.pad(a, ((0, 0), (0, lp - n)))
-    perm = _cyclic_perm(lp, nd, b)
-    return a[:, perm].reshape(m, nd, lp // nd).transpose(1, 0, 2)
+    lb = lp // (nd * b)
+    return (a.reshape(m, lb, nd, b).transpose(2, 0, 1, 3)
+            .reshape(nd, m, lb * b))
 
 
 def from_block_cyclic(a_cyc: jnp.ndarray, b: int,
                       n: Optional[int] = None) -> jnp.ndarray:
     """Inverse of :func:`to_block_cyclic`; ``n`` drops the ragged padding."""
+    a_cyc = _replicated(a_cyc)
     nd, m, l = a_cyc.shape
-    lp = nd * l
-    flat = a_cyc.transpose(1, 0, 2).reshape(m, lp)
-    perm = _cyclic_perm(lp, nd, b)
-    inv = np.argsort(perm)
-    out = flat[:, inv]
+    out = (a_cyc.reshape(nd, m, l // b, b).transpose(1, 2, 0, 3)
+           .reshape(m, nd * l))
     return out if n is None else out[:, :n]
 
 
@@ -206,28 +195,25 @@ def to_block_cyclic_2d(a: jnp.ndarray, grid: Tuple[int, int], br: int,
     are zero-padded like the 1-D case.  Layout-layer only: the engine keeps
     the 1-D column cycle (module docstring).
     """
+    a = _replicated(a)
     pr, pc = grid
     m, n = a.shape
     mp, np_ = _padded_len(m, pr, br), _padded_len(n, pc, bc)
     if (mp, np_) != (m, n):
         a = jnp.pad(a, ((0, mp - m), (0, np_ - n)))
-    rp = _cyclic_perm(mp, pr, br)
-    cp = _cyclic_perm(np_, pc, bc)
-    arr = a[rp][:, cp]
-    return (arr.reshape(pr, mp // pr, pc, np_ // pc)
-            .transpose(0, 2, 1, 3))
+    mlb, nlb = mp // (pr * br), np_ // (pc * bc)
+    return (a.reshape(mlb, pr, br, nlb, pc, bc).transpose(1, 4, 0, 2, 3, 5)
+            .reshape(pr, pc, mlb * br, nlb * bc))
 
 
 def from_block_cyclic_2d(a_cyc: jnp.ndarray, br: int, bc: int,
                          shape: Optional[Tuple[int, int]] = None
                          ) -> jnp.ndarray:
     """Inverse of :func:`to_block_cyclic_2d`; ``shape`` drops the padding."""
+    a_cyc = _replicated(a_cyc)
     pr, pc, mloc, nloc = a_cyc.shape
-    mp, np_ = pr * mloc, pc * nloc
-    flat = a_cyc.transpose(0, 2, 1, 3).reshape(mp, np_)
-    rinv = np.argsort(_cyclic_perm(mp, pr, br))
-    cinv = np.argsort(_cyclic_perm(np_, pc, bc))
-    out = flat[rinv][:, cinv]
+    out = (a_cyc.reshape(pr, pc, mloc // br, br, nloc // bc, bc)
+           .transpose(2, 0, 3, 4, 1, 5).reshape(pr * mloc, pc * nloc))
     if shape is not None:
         out = out[: shape[0], : shape[1]]
     return out
@@ -236,6 +222,8 @@ def from_block_cyclic_2d(a_cyc: jnp.ndarray, br: int, bc: int,
 # ---------------------------------------------------------------------------
 # Jitted shard_map step factories — one XLA executable per (site, shape),
 # cached so repeated factorizations (benches, sweeps) pay zero retracing.
+# The replication (VMA) checker is off: it mis-handles the replicated values
+# these steps produce, and numerics do not depend on it.
 # Every step mirrors one single-device engine hook; ``g = lj·nd + me`` is
 # the global block index of local slot ``lj`` on device ``me``.
 # ---------------------------------------------------------------------------
@@ -251,7 +239,7 @@ def _bcast_step(mesh: Mesh, axis: str, slot: int, owner: int, b: int):
         blk = al[0][:, slot * b : (slot + 1) * b]
         return lax.all_gather(blk, axis)[owner]
 
-    return jax.jit(_shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
                               in_specs=(P(axis, None, None),),
                               out_specs=P()))
 
@@ -267,7 +255,7 @@ def _store_step(mesh: Mesh, axis: str, slot: int, owner: int, b: int):
         new = jnp.where(me == owner, blk_new, cur)
         return a.at[:, slot * b : (slot + 1) * b].set(new)[None]
 
-    return jax.jit(_shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
                               in_specs=(P(axis, None, None), P()),
                               out_specs=P(axis, None, None)))
 
@@ -291,7 +279,7 @@ def _swap_step(mesh: Mesh, axis: str, nd: int, b: int, kb: int, k: int):
             a = a.at[:, lj * b : (lj + 1) * b].set(blk)
         return a[None]
 
-    return jax.jit(_shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
                               in_specs=(P(axis, None, None), P()),
                               out_specs=P(axis, None, None)))
 
@@ -328,7 +316,7 @@ def _lu_update_step(mesh: Mesh, axis: str, nd: int, b: int, k: int, bk: int,
             a = a.at[:, lj * b : (lj + 1) * b].set(blk)
         return a[None]
 
-    return jax.jit(_shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
                               in_specs=(P(axis, None, None), P()),
                               out_specs=P(axis, None, None)))
 
@@ -361,7 +349,7 @@ def _chol_update_step(mesh: Mesh, axis: str, nd: int, b: int, k: int,
             a = a.at[:, lj * b : (lj + 1) * b].set(blk)
         return a[None]
 
-    return jax.jit(_shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
                               in_specs=(P(axis, None, None), P(), P()),
                               out_specs=P(axis, None, None)))
 
@@ -388,7 +376,7 @@ def _qr_update_step(mesh: Mesh, axis: str, nd: int, b: int, k: int,
             a = a.at[:, lj * b : (lj + 1) * b].set(blk)
         return a[None]
 
-    return jax.jit(_shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
                               in_specs=(P(axis, None, None), P(), P()),
                               out_specs=P(axis, None, None)))
 

@@ -12,3 +12,19 @@ Kernel inventory (each with a pure-jnp oracle in :mod:`repro.kernels.ref`):
 
 Public entry points live in :mod:`repro.kernels.ops`.
 """
+
+
+def single_cell_params():
+    """Mosaic compiler params of the whole-panel (``grid=(1,)``) kernels.
+
+    Their working set is resident for the whole sweep, so they ask for
+    :attr:`~repro.tune.model.Machine.vmem_kernel_limit_bytes` of scoped
+    VMEM instead of Mosaic's default.  Imports are local: this package's
+    init must not pull in ``repro.tune`` (and through it ``repro.core``).
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.tune.model import MACHINE
+
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=MACHINE.vmem_kernel_limit_bytes)

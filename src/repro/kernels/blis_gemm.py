@@ -32,6 +32,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -64,6 +65,7 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, ksteps: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                            precision=lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == ksteps - 1)
@@ -116,6 +118,7 @@ def _gemm_accum_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *,
         acc_ref[...] = c_ref[...].astype(jnp.float32)
 
     acc_ref[...] += alpha * jnp.dot(a_ref[...], b_ref[...],
+                                    precision=lax.Precision.HIGHEST,
                                     preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == ksteps - 1)

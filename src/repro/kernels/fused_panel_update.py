@@ -22,52 +22,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-
-def _substitute(l: jnp.ndarray, b: jnp.ndarray, unit: bool) -> jnp.ndarray:
-    """Forward substitution L·X = B on VMEM-resident values."""
-    nb = l.shape[0]
-    rows = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
-
-    def body(i, x):
-        li = lax.dynamic_slice_in_dim(l, i, 1, axis=0)
-        solved = jnp.where(rows < i, x, 0.0)
-        contrib = jnp.dot(li, solved, preferred_element_type=jnp.float32)
-        bi = lax.dynamic_slice_in_dim(x, i, 1, axis=0)
-        div = jnp.float32(1.0) if unit else l[i, i]
-        xi = (bi - contrib) / div
-        return lax.dynamic_update_slice_in_dim(x, xi, i, axis=0)
-
-    return lax.fori_loop(0, nb, body, b)
-
-
-def _lu_factor_inplace(a: jnp.ndarray):
-    """Masked GETF2 on a VMEM-resident (m × nb) value; returns (a, piv)."""
-    m, nb = a.shape
-    rows = lax.broadcasted_iota(jnp.int32, (m, 1), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (1, nb), 1)
-
-    def body(j, carry):
-        a, piv = carry
-        colj = lax.dynamic_slice_in_dim(a, j, 1, axis=1)
-        cand = jnp.where(rows < j, -jnp.inf, jnp.abs(colj))
-        p = jnp.argmax(cand, axis=0)[0].astype(jnp.int32)
-        piv = lax.dynamic_update_slice_in_dim(piv, p[None, None], j, axis=0)
-        rj = lax.dynamic_slice_in_dim(a, j, 1, axis=0)
-        rp = lax.dynamic_slice_in_dim(a, p, 1, axis=0)
-        a = lax.dynamic_update_slice_in_dim(a, rj, p, axis=0)
-        a = lax.dynamic_update_slice_in_dim(a, rp, j, axis=0)
-        pivval = lax.dynamic_slice(a, (j, j), (1, 1))
-        colj = lax.dynamic_slice_in_dim(a, j, 1, axis=1)
-        l = jnp.where(rows > j, colj / pivval, 0.0)
-        rowj = lax.dynamic_slice_in_dim(a, j, 1, axis=0)
-        u = jnp.where(cols > j, rowj, 0.0)
-        a = a - l * u
-        newcol = jnp.where(rows > j, l, lax.dynamic_slice_in_dim(a, j, 1, 1))
-        a = lax.dynamic_update_slice_in_dim(a, newcol, j, axis=1)
-        return a, piv
-
-    piv0 = jnp.zeros((nb, 1), jnp.int32)
-    return lax.fori_loop(0, min(m, nb), body, (a, piv0))
+from repro.kernels import single_cell_params
+from repro.kernels.panel_lu import getf2, pick
+from repro.kernels.trsm import substitute
 
 
 # ---------------------------------------------------------------------------
@@ -83,12 +40,13 @@ def _lu_pu_body(l11, l21, a1l, a2l):
     l11 = l11.astype(jnp.float32)
     l21 = l21.astype(jnp.float32)
     # 1. U12 = L11⁻¹ · A1L            (unit-lower substitution)
-    u12 = _substitute(l11, a1l.astype(jnp.float32), unit=True)
+    u12 = substitute(l11, a1l.astype(jnp.float32), unit=True)
     # 2. panel = A2L − L21 · U12      (MXU contraction, TU_k^L)
     panel = a2l.astype(jnp.float32) - jnp.dot(
-        l21, u12, preferred_element_type=jnp.float32)
+        l21, u12, precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     # 3. PF_{k+1}                     (GETF2 with partial pivoting)
-    packed, piv = _lu_factor_inplace(panel)
+    packed, piv = getf2(panel)
     return u12, packed, piv
 
 
@@ -117,6 +75,7 @@ def fused_lu_panel_update(l11, l21, a1l, a2l, *, interpret: bool = False):
     """
     b = l11.shape[0]
     m, bn = a2l.shape
+    steps = min(m, bn)
     u12, out, piv = pl.pallas_call(
         _fused_lu_pu_kernel,
         grid=(1,),
@@ -129,13 +88,14 @@ def fused_lu_panel_update(l11, l21, a1l, a2l, *, interpret: bool = False):
         out_specs=[
             pl.BlockSpec((b, bn), lambda i: (0, 0)),
             pl.BlockSpec((m, bn), lambda i: (0, 0)),
-            pl.BlockSpec((bn, 1), lambda i: (0, 0)),
+            pl.BlockSpec((steps, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, bn), a1l.dtype),
             jax.ShapeDtypeStruct((m, bn), a2l.dtype),
-            jax.ShapeDtypeStruct((bn, 1), jnp.int32),
+            jax.ShapeDtypeStruct((steps, 1), jnp.int32),
         ],
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(l11, l21, a1l, a2l)
     return u12, out, piv[:, 0]
@@ -147,15 +107,16 @@ def fused_lu_panel_update(l11, l21, a1l, a2l, *, interpret: bool = False):
 def _chol_factor_top(a: jnp.ndarray, nb: int) -> jnp.ndarray:
     """Masked unblocked Cholesky of the top (nb × nb) of a VMEM value."""
     rows = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, nb), 1)
 
     def body(j, a):
-        d = jnp.sqrt(lax.dynamic_slice(a, (j, j), (1, 1)))
-        colj = lax.dynamic_slice_in_dim(a, j, 1, axis=1)
+        colj = pick(a, cols == j, 1)
+        d = jnp.sqrt(pick(colj, rows == j, 0))
         col = jnp.where(rows > j, colj / d, 0.0)
-        a = a - col * col.T
-        newcol = jnp.where(rows > j, col, lax.dynamic_slice_in_dim(a, j, 1, 1))
+        a = a - col * pick(col, rows == cols, 0)        # col · colᵀ
+        newcol = jnp.where(rows > j, col, pick(a, cols == j, 1))
         newcol = jnp.where(rows == j, d, newcol)
-        return lax.dynamic_update_slice_in_dim(a, newcol, j, axis=1)
+        return jnp.where(cols == j, newcol, a)
 
     return lax.fori_loop(0, nb, body, a)
 
@@ -170,12 +131,13 @@ def _chol_pu_body(lrow, l21, panel, bn):
     l21 = l21.astype(jnp.float32)                   # (m, b)
     panel = panel.astype(jnp.float32)               # (m, bn)
     # 1. TU_k^L : panel −= L21 · lrowᵀ
-    panel = panel - jnp.dot(l21, lrow.T, preferred_element_type=jnp.float32)
+    panel = panel - jnp.dot(l21, lrow.T, precision=lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     # 2. PF_{k+1}: factor diag block (tril: match the oracle's zeroed
     #    upper triangle), substitute the rest
     top = jnp.tril(_chol_factor_top(panel[:bn], bn))
     if panel.shape[0] > bn:                         # static shape check
-        rest = _substitute(top, panel[bn:].T, unit=False).T  # X·L11ᵀ = A21
+        rest = substitute(top, panel[bn:].T, unit=False).T  # X·L11ᵀ = A21
         return jnp.concatenate([top, rest])
     return top
 
@@ -210,5 +172,6 @@ def fused_cholesky_panel_update(lrow, l21, panel, *, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, bn), panel.dtype),
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(lrow, l21, panel)

@@ -1,10 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels + the Pallas BLAS backend.
 
-On CPU (this container) every kernel runs with ``interpret=True`` — the
-kernel body executes eagerly in Python on the CPU backend, validating the
-exact dataflow that Mosaic would compile for TPU.  On a TPU backend the same
-entry points compile natively.  Toggle explicitly with
-``set_interpret(True/False)`` if needed.
+Interpret mode is decided at each call from the default backend: on a
+TPU every kernel compiles natively through Mosaic and never runs
+interpreted; on any other backend (the CPU test suite) the kernel body runs
+with ``interpret=True``, validating the same dataflow.  A TPU has no f64
+vector unit, so a wrapper handed f64 there raises :class:`KernelDtypeError`
+instead of running something else.
 
 ``PALLAS_BACKEND`` plugs into :mod:`repro.core.backend` so every DMF driver
 can run on top of the paper-analogous BLIS kernels; its ``panel_fns`` /
@@ -27,6 +28,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.backend import Backend, trsm_jnp
 from repro.kernels import blis_gemm as _bg
@@ -40,17 +42,47 @@ from repro.kernels import trsm as _tr
 from repro.obs import tracer as _obs
 from repro.tune.model import MACHINE
 
-# interpret=True on CPU (validation), False on TPU (deployment).
-_INTERPRET = jax.default_backend() == "cpu"
-
 # largest working set (bytes, at the input dtype) a single-cell kernel may
 # claim in VMEM before falling back — single-sourced from the machine record.
 VMEM_PANEL_BUDGET = MACHINE.vmem_panel_budget_bytes
 
 
-def set_interpret(flag: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = flag
+class KernelDtypeError(TypeError):
+    """A Pallas kernel was handed a dtype the TPU cannot run (f64)."""
+
+
+class KernelNotLoweredError(NotImplementedError):
+    """The kernel has no Mosaic lowering yet, so it cannot run on a TPU."""
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _interpret(*arrays) -> bool:
+    """Interpret mode for this call: off on a TPU, on everywhere else.
+
+    On a TPU, f64 operands raise :class:`KernelDtypeError`.
+    """
+    if not _on_tpu():
+        return True
+    for x in arrays:
+        if x.dtype == jnp.float64:
+            raise KernelDtypeError(
+                f"Pallas kernels take f32/bf16 on a TPU, got {x.dtype}")
+    return False
+
+
+def _require_lowering(name: str) -> None:
+    """Raise on a TPU for a kernel Mosaic cannot compile yet.
+
+    The QR-family sweeps (``qr_unblocked``, ``_qrcp_sweep``,
+    ``_hessenberg_sweep``) still index values dynamically and call the
+    canonical GEMM, whose K loop slices values; Mosaic refuses both.
+    """
+    if _on_tpu():
+        raise KernelNotLoweredError(
+            f"{name}: no TPU lowering yet; use backend='jnp' on a TPU")
 
 
 def _nbytes(itemsize: int, *shapes) -> int:
@@ -85,24 +117,24 @@ def _note_fallback(name: str, *shapes) -> None:
 @functools.partial(jax.jit, static_argnames=("blocks",))
 def gemm(a, b, blocks=None):
     """C = A·B via the BLIS five-loop Pallas kernel."""
-    return _bg.blis_gemm(a, b, blocks=blocks, interpret=_INTERPRET)
+    return _bg.blis_gemm(a, b, blocks=blocks, interpret=_interpret(a, b))
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "blocks"))
 def gemm_accum(c, a, b, alpha=-1.0, blocks=None):
     """O = C + alpha·A·B (fused trailing update)."""
     return _bg.blis_gemm_accum(c, a, b, alpha=alpha, blocks=blocks,
-                               interpret=_INTERPRET)
+                               interpret=_interpret(c, a, b))
 
 
 def trsm(t, b, *, side="left", lower=True, trans=False, unit_diagonal=False):
     """Backend-compatible TRSM; Pallas path for the two DMF shapes."""
     if side == "left" and lower and not trans:
         return _tr.trsm_left_lower(t, b, unit_diagonal=unit_diagonal,
-                                   interpret=_INTERPRET)
+                                   interpret=_interpret(t, b))
     if side == "right" and lower and trans:
         return _tr.trsm_right_lower_t(t, b, unit_diagonal=unit_diagonal,
-                                      interpret=_INTERPRET)
+                                      interpret=_interpret(t, b))
     # other combinations are not on the DMF hot path — defer to XLA
     return trsm_jnp(t, b, side=side, lower=lower, trans=trans,
                     unit_diagonal=unit_diagonal)
@@ -121,7 +153,7 @@ def lu_solve_small(lu, b):
         _note_fallback("lu_solve_small", lu.shape, b.shape)
         y = trsm_jnp(lu, b, side="left", lower=True, unit_diagonal=True)
         return trsm_jnp(lu, y, side="left", lower=False)
-    return _tr.lu_solve_small(lu, b, interpret=_INTERPRET)
+    return _tr.lu_solve_small(lu, b, interpret=_interpret(lu, b))
 
 
 # ---------------------------------------------------------------------------
@@ -138,17 +170,18 @@ def lu_panel(panel):
             > VMEM_PANEL_BUDGET:
         _note_fallback("lu_panel", panel.shape)
         return _panels.lu_panel(panel)
-    return _plu.lu_panel(panel, interpret=_INTERPRET)
+    return _plu.lu_panel(panel, interpret=_interpret(panel))
 
 
 def qr_panel(panel):
     """GEQR2+LARFT panel kernel; traced-panel fallback."""
+    _require_lowering("qr_panel")
     m, nb = panel.shape
     if _nbytes(panel.dtype.itemsize, (m, nb), (m, nb), (nb, nb)) \
             > VMEM_PANEL_BUDGET:
         _note_fallback("qr_panel", panel.shape)
         return _panels.qr_panel(panel)
-    return _pqr.qr_panel(panel, interpret=_INTERPRET)
+    return _pqr.qr_panel(panel, interpret=_interpret(panel))
 
 
 def qrcp_panel(block, steps):
@@ -158,23 +191,25 @@ def qrcp_panel(block, steps):
     ``qrcp_local`` windowed-pivoting contract (bare window) — same as the
     traced ``panels.qrcp_panel`` it falls back to.
     """
+    _require_lowering("qrcp_panel")
     r, c = block.shape
     if _nbytes(block.dtype.itemsize,
                (r, c), (r, c), (r, steps), (c, steps)) > VMEM_PANEL_BUDGET:
         _note_fallback("qrcp_panel", block.shape)
         return _panels.qrcp_panel(block, steps)
-    return _pqrcp.qrcp_panel(block, steps, interpret=_INTERPRET)
+    return _pqrcp.qrcp_panel(block, steps, interpret=_interpret(block))
 
 
 def hessenberg_panel(a, k, bk):
     """xLAHR2 panel kernel (whole matrix + V/T/W aux VMEM-resident)."""
+    _require_lowering("hessenberg_panel")
     n = a.shape[0]
     if _nbytes(a.dtype.itemsize,
                (n, n), (n, n), (n, bk), (n, bk), (bk, bk)) \
             > VMEM_PANEL_BUDGET:
         _note_fallback("hessenberg_panel", a.shape)
         return _panels.hessenberg_panel(a, k, bk)
-    return _phs.hessenberg_panel(a, k, bk, interpret=_INTERPRET)
+    return _phs.hessenberg_panel(a, k, bk, interpret=_interpret(a))
 
 
 # ---------------------------------------------------------------------------
@@ -188,8 +223,8 @@ def fused_lu_panel_update(l11, l21, a1l, a2l):
             > VMEM_PANEL_BUDGET:
         _note_fallback("fused_lu_pu", l21.shape, a2l.shape)
         return _fpu.fused_lu_panel_update_ref(l11, l21, a1l, a2l)
-    return _fpu.fused_lu_panel_update(l11, l21, a1l, a2l,
-                                      interpret=_INTERPRET)
+    return _fpu.fused_lu_panel_update(
+        l11, l21, a1l, a2l, interpret=_interpret(l11, l21, a1l, a2l))
 
 
 def fused_cholesky_panel_update(lrow, l21, panel):
@@ -198,8 +233,8 @@ def fused_cholesky_panel_update(lrow, l21, panel):
             > VMEM_PANEL_BUDGET:
         _note_fallback("fused_chol_pu", l21.shape, panel.shape)
         return _fpu.fused_cholesky_panel_update_ref(lrow, l21, panel)
-    return _fpu.fused_cholesky_panel_update(lrow, l21, panel,
-                                            interpret=_INTERPRET)
+    return _fpu.fused_cholesky_panel_update(
+        lrow, l21, panel, interpret=_interpret(lrow, l21, panel))
 
 
 # resolved by repro.core.lookahead.get_variant("<dmf>", "la_mb") — composes
@@ -219,7 +254,8 @@ FUSED_PU = {
 #
 # All five panel contracts now resolve to VMEM-resident Pallas kernels
 # (lu / qr / qrcp / qrcp_local / hessenberg — each with the traced-panel
-# fallback above); ldlt stays traced from ``panels.TRACED_PANELS`` (its
+# fallback above).  On a TPU only lu compiles; the QR-family wrappers raise
+# ``KernelNotLoweredError`` there (``_require_lowering``).  ldlt stays traced from ``panels.TRACED_PANELS`` (its
 # panel is a backend-TRSM diagonal sweep, nothing to pin in VMEM).  The
 # traced forms stay reachable as ``panels.TRACED_PANELS[...]`` for explicit
 # selection (the tuner's traced-vs-pallas panel axis).  cholesky and

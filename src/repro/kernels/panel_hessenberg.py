@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import single_cell_params
+
 
 def _hessenberg_panel_kernel(a_ref, k_ref, a_out_ref, v_ref, t_ref, w_ref,
                              tau_ref, *, bk: int):
@@ -63,6 +65,7 @@ def hessenberg_panel(a: jnp.ndarray, k, bk: int, *, interpret: bool = False):
             jax.ShapeDtypeStruct((n, bk), a.dtype),
             jax.ShapeDtypeStruct((bk, 1), a.dtype),
         ],
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(a, karr)
     return a_out, v, t, w, tau[:, 0]

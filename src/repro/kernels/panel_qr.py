@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import single_cell_params
+
 
 def _qr_panel_kernel(a_ref, out_ref, tau_ref, t_ref):
     from repro.core.qr import build_t_matrix, qr_unblocked, unpack_v
@@ -45,6 +47,7 @@ def qr_panel(panel: jnp.ndarray, *, interpret: bool = False):
             jax.ShapeDtypeStruct((nb, 1), panel.dtype),
             jax.ShapeDtypeStruct((nb, nb), panel.dtype),
         ],
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(panel)
     return out, tau[:, 0], t

@@ -12,8 +12,10 @@ The kernel body traces :func:`repro.kernels.panels._qrcp_sweep` — the exact
 function behind the traced (PR 5) panel — over the VMEM-resident value, so
 the Pallas panel is **bitwise identical** to the traced panel on the
 interpret backend, which is what makes the VMEM-budget fallback in
-``kernels/ops.py`` transparent.  Runs in the input dtype (f64 validated in
-interpret mode; on real TPU hardware f64 panels take the traced path).
+``kernels/ops.py`` transparent.  Runs in the input dtype in interpret mode
+(f64 included).  The sweep still indexes values dynamically, which Mosaic
+cannot lower, so on a TPU the ``ops.qrcp_panel`` wrapper raises
+``KernelNotLoweredError``.
 
 Same routine serves both registry keys: ``qrcp`` hands it the full trailing
 block (global greedy pivoting) and ``qrcp_local`` hands it the bare
@@ -26,6 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import single_cell_params
 
 
 def _qrcp_panel_kernel(block_ref, b_ref, v_ref, f_ref, tau_ref, piv_ref, *,
@@ -63,6 +67,7 @@ def qrcp_panel(block: jnp.ndarray, steps: int, *, interpret: bool = False):
             jax.ShapeDtypeStruct((steps, 1), block.dtype),
             jax.ShapeDtypeStruct((steps, 1), jnp.int32),
         ],
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(block)
     return b, v, f, tau[:, 0], piv[:, 0]

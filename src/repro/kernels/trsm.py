@@ -19,23 +19,41 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels import single_cell_params
+from repro.kernels.panel_lu import pick
 
-def _trsm_kernel(l_ref, b_ref, x_ref, *, nb: int, unit: bool):
-    l = l_ref[...].astype(jnp.float32)
-    x = b_ref[...].astype(jnp.float32)
-    rows = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
 
-    def body(i, x):
-        li = lax.dynamic_slice_in_dim(l, i, 1, axis=0)      # (1, nb)
-        solved = jnp.where(rows < i, x, 0.0)                # rows < i final
-        contrib = jnp.dot(li, solved,
+def substitute(t: jnp.ndarray, x: jnp.ndarray, *, lower: bool = True,
+               unit: bool = True) -> jnp.ndarray:
+    """Solve ``T·X = B`` by row substitution on VMEM-resident values.
+
+    ``t`` is (n × n) triangular, ``x`` holds B (n × bn); both f32.  Row i
+    is read and written through one-hot masks (``repro.kernels.panel_lu.pick``), the
+    form a Pallas TPU kernel can lower, and the rows already solved enter
+    one (1 × n)·(n × bn) MXU product.  Shared by the TRSM, fused LU solve
+    and fused panel-update kernels and by their traced twins.
+    """
+    n = t.shape[0]
+    rows = lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+    def body(s, x):
+        i = s if lower else n - 1 - s
+        ti = pick(t, rows == i, 0)                          # (1, n)
+        solved = jnp.where(rows < i if lower else rows > i, x, 0.0)
+        contrib = jnp.dot(ti, solved, precision=lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)  # (1, bn)
-        bi = lax.dynamic_slice_in_dim(x, i, 1, axis=0)
-        div = jnp.float32(1.0) if unit else l[i, i]
-        xi = (bi - contrib) / div
-        return lax.dynamic_update_slice_in_dim(x, xi, i, axis=0)
+        xi = pick(x, rows == i, 0) - contrib
+        if not unit:
+            xi = xi / pick(ti, cols == i, 1)
+        return jnp.where(rows == i, xi, x)
 
-    x = lax.fori_loop(0, nb, body, x)
+    return lax.fori_loop(0, n, body, x)
+
+
+def _trsm_kernel(l_ref, b_ref, x_ref, *, unit: bool):
+    x = substitute(l_ref[...].astype(jnp.float32),
+                   b_ref[...].astype(jnp.float32), unit=unit)
     x_ref[...] = x.astype(x_ref.dtype)
 
 
@@ -53,7 +71,7 @@ def trsm_left_lower(l: jnp.ndarray, b: jnp.ndarray, *,
         b = jnp.pad(b, ((0, 0), (0, npad - n)))
 
     out = pl.pallas_call(
-        functools.partial(_trsm_kernel, nb=nb, unit=unit_diagonal),
+        functools.partial(_trsm_kernel, unit=unit_diagonal),
         grid=(npad // bn,),
         in_specs=[
             pl.BlockSpec((nb, nb), lambda j: (0, 0)),   # L resident per step
@@ -61,6 +79,7 @@ def trsm_left_lower(l: jnp.ndarray, b: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((nb, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((nb, npad), b.dtype),
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(l, b)
     return out[:, :n]
@@ -78,37 +97,19 @@ def trsm_right_lower_t(l: jnp.ndarray, b: jnp.ndarray, *,
 # ---------------------------------------------------------------------------
 # Fused small-RHS LU solve — the solve layer's LA_MB analogue.
 # ---------------------------------------------------------------------------
-def _lu_solve_kernel(lu_ref, b_ref, x_ref, *, n: int):
+def _lu_solve_kernel(lu_ref, b_ref, x_ref):
     """Forward (unit-lower) + backward (upper) substitution in one kernel.
 
     The packed LU stays VMEM-resident for both sweeps — for the small
     factor-once/solve-many systems of the serving scenario the two
     substitutions are latency-bound, so fusing them removes one full
-    HBM round-trip of the factor (DESIGN.md §8).
+    HBM round-trip of the factor (DESIGN.md §8).  The strictly-upper half
+    is masked out of the forward sweep and the strictly-lower half out of
+    the backward one by the ``solved`` row masks.
     """
     a = lu_ref[...].astype(jnp.float32)
-    x = b_ref[...].astype(jnp.float32)
-    rows = lax.broadcasted_iota(jnp.int32, (n, 1), 0)
-
-    def fwd(i, x):
-        ai = lax.dynamic_slice_in_dim(a, i, 1, axis=0)       # (1, n)
-        solved = jnp.where(rows < i, x, 0.0)
-        contrib = jnp.dot(ai, solved, preferred_element_type=jnp.float32)
-        bi = lax.dynamic_slice_in_dim(x, i, 1, axis=0)
-        return lax.dynamic_update_slice_in_dim(x, bi - contrib, i, axis=0)
-
-    x = lax.fori_loop(0, n, fwd, x)                          # L·y = b (unit)
-
-    def bwd(t, x):
-        i = n - 1 - t
-        ai = lax.dynamic_slice_in_dim(a, i, 1, axis=0)
-        solved = jnp.where(rows > i, x, 0.0)
-        contrib = jnp.dot(ai, solved, preferred_element_type=jnp.float32)
-        bi = lax.dynamic_slice_in_dim(x, i, 1, axis=0)
-        xi = (bi - contrib) / a[i, i]
-        return lax.dynamic_update_slice_in_dim(x, xi, i, axis=0)
-
-    x = lax.fori_loop(0, n, bwd, x)                          # U·x = y
+    y = substitute(a, b_ref[...].astype(jnp.float32), lower=True, unit=True)
+    x = substitute(a, y, lower=False, unit=False)
     x_ref[...] = x.astype(x_ref.dtype)
 
 
@@ -125,7 +126,7 @@ def lu_solve_small(lu: jnp.ndarray, b: jnp.ndarray, *,
         b = jnp.pad(b, ((0, 0), (0, npad - nrhs)))
 
     out = pl.pallas_call(
-        functools.partial(_lu_solve_kernel, n=n),
+        _lu_solve_kernel,
         grid=(npad // bn,),
         in_specs=[
             pl.BlockSpec((n, n), lambda j: (0, 0)),     # LU resident per step
@@ -133,6 +134,7 @@ def lu_solve_small(lu: jnp.ndarray, b: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((n, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((n, npad), b.dtype),
+        compiler_params=single_cell_params(),
         interpret=interpret,
     )(lu, b)
     return out[:, :nrhs]

@@ -54,6 +54,11 @@ class Machine:
     #: ceiling for a whole-panel single-cell kernel (panel + outputs); the
     #: ``ops.py`` wrappers fall back to the traced panels above this.
     vmem_panel_budget_bytes: int = 10 * 1024 * 1024
+    #: scoped VMEM a whole-panel kernel asks Mosaic for.  ``vmem_bytes`` is
+    #: Mosaic's default scope; a panel at the budget above, double-buffered
+    #: in and out, needs about three times the budget (a 4096 × 256 f32 LU
+    #: panel takes 18 MiB).  Half of the v5e core's 128 MiB of VMEM.
+    vmem_kernel_limit_bytes: int = 64 * 1024 * 1024
     lane: int = 128                       # MXU/VPU lane width (last dim)
     sublane_f32: int = 8                  # second-minor tile, f32
     sublane_bf16: int = 16                # second-minor tile, bf16

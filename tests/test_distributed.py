@@ -118,6 +118,22 @@ for dmf in ("lu", "cholesky", "qr"):
                 got = fn(a, b, backend=be, mesh=mesh)
                 out[f"{dmf}_{variant}_{dt}_n{n}"] = exact(ref, got)
 
+# the mesh's axis types: jax.make_mesh gives Explicit axes by default; the
+# engine and the layout helpers must work on Explicit and Auto alike
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+from repro.core.distributed import from_block_cyclic, to_block_cyclic
+a = jnp.asarray(np.random.default_rng(12).standard_normal((64, 64))
+                .astype(np.float32))
+for kind in ("Explicit", "Auto"):
+    m4 = jax.make_mesh((4,), ("model",),
+                       axis_types=(getattr(AxisType, kind),))
+    fn = get_variant("lu", "la")
+    out[f"axis_{kind}_lu"] = exact(fn(a, b, backend=be),
+                                   fn(a, b, backend=be, mesh=m4))
+    cyc = jax.device_put(to_block_cyclic(a, 4, b),
+                         NamedSharding(m4, P("model", None, None)))
+    out[f"axis_{kind}_roundtrip"] = exact(a, from_block_cyclic(cyc, b))
+
 # solve drivers: mesh= accepted, bitwise vs the single-device path
 a = jnp.asarray(rng.standard_normal((64, 64)))
 rhs = jnp.asarray(rng.standard_normal((64, 3)))
@@ -235,6 +251,15 @@ def test_mesh_variants_bitwise(matrix_result):
     assert len(cells) == 3 * 3 * 2 * 2          # dmf × variant × dtype × n
     bad = [k for k, ok in cells.items() if not ok]
     assert not bad, bad
+
+
+@pytest.mark.parametrize("kind", ["Explicit", "Auto"])
+def test_mesh_axis_types(matrix_result, kind):
+    """``AxisType.Explicit`` meshes (the ``jax.make_mesh`` default) and
+    ``Auto`` ones both run the engine bitwise and round-trip a sharded
+    block-cyclic array."""
+    assert matrix_result[f"axis_{kind}_lu"]
+    assert matrix_result[f"axis_{kind}_roundtrip"]
 
 
 def test_solve_drivers_accept_mesh(matrix_result):
