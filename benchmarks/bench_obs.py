@@ -2,19 +2,21 @@
 
     PYTHONPATH=src python -m benchmarks.bench_obs \
         [--n N] [--b B] [--dmfs lu,cholesky] [--variants mtb,la,la2] \
-        [--trace-dir DIR] [--json PATH] [--no-hlo] [--small]
+        [--json PATH] [--no-hlo] [--small]
 
 For each (dmf, variant) the factorization runs **eagerly** under an
 installed :class:`repro.obs.Tracer` (tracing a jitted run would time trace
-construction, not device work), then three artifacts are produced:
+construction, not device work), then two artifacts are produced:
 
-* a Chrome/Perfetto trace — ``{trace_dir}/obs_{dmf}_{variant}_n{n}.json``,
-  loadable at ``ui.perfetto.dev`` or ``chrome://tracing``;
 * one BENCH_obs.json trajectory row per run: the shared schema
   (``benchmarks.common.validate_rows``) plus ``overlap_efficiency``,
   ``critical_path_s``, ``ideal_speedup`` and the model-vs-measured join
   (``model_s``, ``attainment``, ``hlo_flops``, ``hlo_warnings``);
-* the rendered two-track timeline and the attainment table on stdout.
+* the attainment table on stdout.
+
+For a timeline, run the same calls under ``jax.profiler.trace(dir,
+create_perfetto_trace=True)``: every span is also a profiler annotation
+(DESIGN.md §14).
 
 Overlap efficiency is *structural* (see ``repro.obs.report``): on the
 serializing CPU backend it reports how much panel time the la(d) schedule
@@ -29,7 +31,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -46,7 +47,7 @@ _INPUTS = {
 
 
 def _trace_one(dmf: str, variant: str, n: int, b: int, *, hlo: bool):
-    """One eager traced run → (spans, overlap dict, attainment row)."""
+    """One eager traced run → (overlap dict, attainment row)."""
     import jax
 
     from repro.core.lookahead import get_variant
@@ -68,37 +69,30 @@ def _trace_one(dmf: str, variant: str, n: int, b: int, *, hlo: bool):
     ov = obs_report.overlap(tr.spans)
     row = obs_report.attainment_row(dmf, n, variant, b, tr.spans,
                                     hlo_text=hlo_text)
-    return tr.spans, ov, row
+    return ov, row
 
 
 def run_trace(dmfs=("lu", "cholesky"), variants=("mtb", "la", "la2"),
-              n: int = 512, b: int = 128, trace_dir: str = "traces",
-              json_path: str = "BENCH_obs.json", hlo: bool = True,
-              quiet: bool = False):
-    """Trace every (dmf, variant); write artifacts; return the row dicts."""
-    from repro.obs import export as obs_export
+              n: int = 512, b: int = 128, json_path: str = "BENCH_obs.json",
+              hlo: bool = True, quiet: bool = False):
+    """Trace every (dmf, variant); append the rows; return them."""
     from repro.obs import report as obs_report
 
-    os.makedirs(trace_dir, exist_ok=True)
     commit = git_commit()
     rows, att_rows = [], []
     for dmf in dmfs:
         for variant in variants:
-            spans, ov, att = _trace_one(dmf, variant, n, b, hlo=hlo)
-            label = f"obs_{dmf}_{variant}_n{n}"
-            path = os.path.join(trace_dir, label + ".json")
-            obs_export.write_chrome_trace(path, spans, label=label)
+            ov, att = _trace_one(dmf, variant, n, b, hlo=hlo)
             row = dict(att)
             row.update(ov)
             row.update(bench="obs", wall=ov["wall_s"], commit=commit,
-                       ts=time.time(), trace=path)
+                       ts=time.time())
             rows.append(row)
             att_rows.append(att)
             if not quiet:
-                print(f"# {label}: overlap_efficiency="
+                print(f"# obs_{dmf}_{variant}_n{n}: overlap_efficiency="
                       f"{ov['overlap_efficiency']:.3f} "
                       f"ideal_speedup={ov['ideal_speedup']:.2f}")
-                print(obs_export.render_timeline(spans))
 
     validate_rows(rows)
     with open(json_path, "a") as f:
@@ -106,8 +100,7 @@ def run_trace(dmfs=("lu", "cholesky"), variants=("mtb", "la", "la2"),
             f.write(json.dumps(row, sort_keys=True) + "\n")
     if not quiet:
         print(obs_report.format_attainment(att_rows))
-        print(f"# wrote {len(rows)} rows to {json_path}; "
-              f"traces in {trace_dir}/", file=sys.stderr)
+        print(f"# wrote {len(rows)} rows to {json_path}", file=sys.stderr)
     return rows
 
 
@@ -119,7 +112,6 @@ def main(argv=None):
                     help="comma-separated DMF names "
                          f"(have: {', '.join(_INPUTS)})")
     ap.add_argument("--variants", default="mtb,la,la2")
-    ap.add_argument("--trace-dir", default="traces")
     ap.add_argument("--json", default="BENCH_obs.json")
     ap.add_argument("--no-hlo", action="store_true",
                     help="skip the jit compile that feeds the HLO flop join")
@@ -129,13 +121,11 @@ def main(argv=None):
 
     if args.small:
         rows = run_trace(dmfs=("lu",), variants=("la2",), n=192, b=64,
-                         trace_dir=args.trace_dir, json_path=args.json,
-                         hlo=False)
+                         json_path=args.json, hlo=False)
     else:
         rows = run_trace(dmfs=tuple(args.dmfs.split(",")),
                          variants=tuple(args.variants.split(",")),
-                         n=args.n, b=args.b, trace_dir=args.trace_dir,
-                         json_path=args.json, hlo=not args.no_hlo)
+                         n=args.n, b=args.b, json_path=args.json, hlo=not args.no_hlo)
     missing = [r for r in rows if "overlap_efficiency" not in r]
     if missing:
         sys.exit(f"{len(missing)} rows missing overlap_efficiency")

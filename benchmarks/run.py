@@ -14,9 +14,8 @@ tile-DAG scheduling vs the pipeline variants.
 ``--only`` substring-filters the benchmark groups (so the tuner and CI can
 run targeted sweeps); ``--csv`` writes the aggregated rows to a file.
 ``--trace`` additionally runs the observability pass (bench_obs): traced
-mtb/la/la2 LU + Cholesky runs emitting Chrome/Perfetto traces, terminal
-timelines, the model-vs-measured attainment table, and BENCH_obs.json
-rows.  The trace pass is deliberately *not* subject to ``--only`` — its
+mtb/la/la2 LU + Cholesky runs, the model-vs-measured attainment table,
+and BENCH_obs.json rows.  The trace pass is deliberately *not* subject to ``--only`` — its
 artifacts join LU and Cholesky against the cost model regardless of which
 benchmark groups were selected.
 """
@@ -104,9 +103,7 @@ def main(argv=None) -> None:
                          "bench, n, b, variant, gflops, wall, commit, ts)")
     ap.add_argument("--trace", action="store_true",
                     help="also run the traced observability pass (spans, "
-                         "Chrome traces, overlap/attainment, BENCH_obs.json)")
-    ap.add_argument("--trace-dir", default="traces", metavar="DIR",
-                    help="directory for --trace Chrome/Perfetto artifacts")
+                         "overlap/attainment, BENCH_obs.json)")
     ap.add_argument("--trace-json", default="BENCH_obs.json", metavar="PATH",
                     help="BENCH_obs.json path for --trace rows")
     args = ap.parse_args(argv)
@@ -140,8 +137,7 @@ def main(argv=None) -> None:
 
     if args.trace:
         from benchmarks import bench_obs
-        obs_rows = bench_obs.run_trace(trace_dir=args.trace_dir,
-                                       json_path=args.trace_json)
+        obs_rows = bench_obs.run_trace(json_path=args.trace_json)
         print(f"# trace pass: {len(obs_rows)} BENCH_obs rows",
               file=sys.stderr)
 

@@ -192,6 +192,34 @@ trsm_jnp = functools.wraps(_trsm_impl)(jax.jit(
     static_argnames=("side", "lower", "trans", "unit_diagonal")))
 
 
+#: Device-visible names of the backend's two kernels (DESIGN.md §14).
+GEMM_SCOPE = "repro.gemm"
+TRSM_SCOPE = "repro.trsm"
+
+
+@dataclasses.dataclass(frozen=True)
+class _Scoped:
+    """A kernel entry that runs under ``jax.named_scope(scope)``.
+
+    The scope is HLO metadata only: every op the kernel emits carries it in
+    its ``op_name``, so the profiler's device trace can tell GEMM and TRSM
+    time apart, while the compiled program and its results stay the same.
+    Equality follows the wrapped function, so a :class:`Backend` stays a
+    stable static argument under ``jit``.
+    """
+
+    scope: str
+    fn: Callable[..., jnp.ndarray]
+
+    def __call__(self, *args, **kw):
+        with jax.named_scope(self.scope):
+            return self.fn(*args, **kw)
+
+
+def _scoped(scope: str, fn: Callable) -> _Scoped:
+    return fn if isinstance(fn, _Scoped) else _Scoped(scope, fn)
+
+
 @dataclasses.dataclass(frozen=True)
 class Backend:
     """BLAS-like vtable the DMF drivers are written against.
@@ -212,9 +240,21 @@ class Backend:
     panel_fns: Optional[Mapping[str, Callable]] = None
     fused_pu: Optional[Mapping[str, Callable]] = None
 
+    def __post_init__(self):
+        # Every backend's GEMM and TRSM are entered here, so both the jnp
+        # and the Pallas kernels carry the ``repro.gemm`` / ``repro.trsm``
+        # scopes wherever a DMF or a solve calls them.
+        object.__setattr__(self, "gemm", _scoped(GEMM_SCOPE, self.gemm))
+        object.__setattr__(self, "trsm", _scoped(TRSM_SCOPE, self.trsm))
+
     def update(self, c: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-        """Rank-k update ``C - A·B`` — the trailing-update workhorse."""
-        return (c - self.gemm(a, b)).astype(c.dtype)
+        """Rank-k update ``C - A·B`` — the trailing-update workhorse.
+
+        The subtraction sits inside the GEMM's scope too: the compiler fuses
+        it with the product, and the fused op takes one ``op_name``.
+        """
+        with jax.named_scope(GEMM_SCOPE):
+            return (c - self.gemm.fn(a, b)).astype(c.dtype)
 
 
 JNP_BACKEND = Backend(name="jnp", gemm=gemm_jnp, trsm=trsm_jnp)

@@ -70,6 +70,7 @@ from repro.core.backend import Backend, JNP_BACKEND
 from repro.core.blocking import BlockSpec, PanelStep, normalize_block, panel_steps
 from repro.core.cholesky import CHOLESKY_OPS, cholesky_panel
 from repro.core.lu import LU_OPS, laswp, lu_unblocked
+from repro.core.pipeline import _hook
 from repro.core.qr import QR_OPS, _Panel, _hooked_factor_panel, apply_qt_blocked
 from repro.obs import tracer as _obs
 
@@ -534,12 +535,6 @@ DIST_REGISTRY = {
 # ---------------------------------------------------------------------------
 # The mesh engine: mtb / la(depth-d) orders emitted over shard_map steps.
 # ---------------------------------------------------------------------------
-def _spanned(tr, cat, name, thunk, **tags):
-    if tr is None:
-        return thunk()
-    return tr.wrap(cat, name, thunk, **tags)
-
-
 def factorize_mesh(
     ops,
     a: jnp.ndarray,
@@ -629,21 +624,21 @@ def _run_mesh_mtb(dist, steps, al, aux, geom, backend, panel_fn, tr):
     for i, st in enumerate(steps):
         owner, slot = i % nd, i // nd
         bc = _bcast_step(mesh, axis, slot, owner, b)
-        blk = _spanned(tr, "BCAST", f"BCAST({i})", lambda: bc(al),
-                       step=i, it=i, shard=owner, bytes=nbytes)
-        blk, aux, ctx, piv = _spanned(
+        blk = _hook(tr, "BCAST", f"BCAST({i})", lambda: bc(al),
+                   step=i, it=i, shard=owner, bytes=nbytes)
+        blk, aux, ctx, piv = _hook(
             tr, "PF", f"PF({i})",
             lambda: dist.pf(blk, aux, st, backend, panel_fn, geom),
             step=i, it=i, shard=owner)
         al = _store_step(mesh, axis, slot, owner, b)(al, blk)
         if piv is not None:
             sw = _swap_step(mesh, axis, nd, b, i, st.k)
-            al = _spanned(tr, "SWAP", f"SWAP({i})", lambda: sw(al, piv),
-                          step=i, it=i)
+            al = _hook(tr, "SWAP", f"SWAP({i})", lambda: sw(al, piv),
+                      step=i, it=i)
         if st.k_next < n:
             upd = dist.update(geom, st, "gt", i, st.k_next, backend)
-            al = _spanned(tr, "TU", f"TU({i})", lambda: upd(al, *ctx),
-                          step=i, it=i, cols=(st.k_next, n))
+            al = _hook(tr, "TU", f"TU({i})", lambda: upd(al, *ctx),
+                      step=i, it=i, cols=(st.k_next, n))
     return al, aux
 
 
@@ -662,9 +657,9 @@ def _run_mesh_la(dist, steps, al, aux, geom, backend, panel_fn, depth, tr):
 
     # Prologue: broadcast + factor panel 0 ahead of the loop (it=-1).
     bc0 = _bcast_step(mesh, axis, 0, 0, b)
-    blk = _spanned(tr, "BCAST", "BCAST(0)", lambda: bc0(al),
-                   step=0, it=-1, depth=1, shard=0, bytes=nbytes)
-    blk, aux, ctx, piv = _spanned(
+    blk = _hook(tr, "BCAST", "BCAST(0)", lambda: bc0(al),
+               step=0, it=-1, depth=1, shard=0, bytes=nbytes)
+    blk, aux, ctx, piv = _hook(
         tr, "PF", "PF(0)",
         lambda: dist.pf(blk, aux, steps[0], backend, panel_fn, geom),
         step=0, it=-1, depth=1, shard=0)
@@ -673,8 +668,8 @@ def _run_mesh_la(dist, steps, al, aux, geom, backend, panel_fn, depth, tr):
     for i, st in enumerate(steps):
         if piv is not None:
             sw = _swap_step(mesh, axis, nd, b, i, st.k)
-            al = _spanned(tr, "SWAP", f"SWAP({i})", lambda: sw(al, piv),
-                          step=i, it=i)
+            al = _hook(tr, "SWAP", f"SWAP({i})", lambda: sw(al, piv),
+                      step=i, it=i)
         if st.k_next >= n:
             break
         dd = min(depth, nsteps - 1 - i)
@@ -683,17 +678,17 @@ def _run_mesh_la(dist, steps, al, aux, geom, backend, panel_fn, depth, tr):
             stj = steps[i + j]
             tb = i + j
             upd = dist.update(geom, st, "eq", tb, stj.k, backend)
-            al = _spanned(tr, "PU", f"PU({i}->{tb})",
-                          lambda: upd(al, *ctx),
-                          step=i, it=i, depth=j, cols=(stj.k, stj.k_next),
-                          shard=tb % nd)
+            al = _hook(tr, "PU", f"PU({i}->{tb})",
+                      lambda: upd(al, *ctx),
+                      step=i, it=i, depth=j, cols=(stj.k, stj.k_next),
+                      shard=tb % nd)
             if j == 1:
                 owner, slot = tb % nd, tb // nd
                 bc = _bcast_step(mesh, axis, slot, owner, b)
-                blkj = _spanned(tr, "BCAST", f"BCAST({tb})", lambda: bc(al),
-                                step=tb, it=i, depth=1, shard=owner,
-                                bytes=nbytes)
-                blkj, aux, nctx, npiv = _spanned(
+                blkj = _hook(tr, "BCAST", f"BCAST({tb})", lambda: bc(al),
+                            step=tb, it=i, depth=1, shard=owner,
+                            bytes=nbytes)
+                blkj, aux, nctx, npiv = _hook(
                     tr, "PF", f"PF({tb})",
                     lambda: dist.pf(blkj, aux, stj, backend, panel_fn, geom),
                     step=tb, it=i, depth=1, shard=owner)
@@ -701,8 +696,8 @@ def _run_mesh_la(dist, steps, al, aux, geom, backend, panel_fn, depth, tr):
         r0 = steps[i + dd].k_next if dd >= 1 else st.k_next
         if r0 < n:
             upd = dist.update(geom, st, "gt", i + dd, r0, backend)
-            al = _spanned(tr, "TU", f"TU({i})", lambda: upd(al, *ctx),
-                          step=i, it=i, cols=(r0, n), inflight=dd)
+            al = _hook(tr, "TU", f"TU({i})", lambda: upd(al, *ctx),
+                      step=i, it=i, cols=(r0, n), inflight=dd)
         if nctx is not None:
             ctx, piv = nctx, npiv
     return al, aux

@@ -41,6 +41,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.backend import Backend, JNP_BACKEND
@@ -215,18 +216,36 @@ def factorize(
 
 
 # ---------------------------------------------------------------------------
-# MTB: PF(k) ; barrier ; TU(k) over the whole trailing matrix (Listing 3).
-#
 # Observability (DESIGN.md §14): every hook invocation in the three loops
-# below is bracketed by a span when a tracer is installed
-# (``repro.obs.tracer.trace()``).  With no tracer — the default — each site
-# costs exactly one ``tr is None`` predicate and runs the original call
-# unchanged, so disabled tracing is bitwise invisible; with a tracer, spans
-# only add timestamps and (optionally) ``block_until_ready`` fences around
-# the already-emitted op sequence — they observe the schedule, never
-# reorder it.  Span tags: ``step`` = panel index k, ``it`` = the iteration
-# that ran the work, ``depth`` = step − it, the in-flight distance that
-# makes la(d) overlap visible in the exported timeline.
+# below runs through :func:`_hook`, which opens the device-visible scope
+# ``repro.<CAT>`` (``PF``, ``SWAP``, ``PU``, ``TU``, ``EPI``) around it.  A
+# scope is HLO metadata only — it names the ops in the compiled program's
+# ``op_name`` and so in the profiler's device trace, adds no op and changes
+# no bit.  Only when a host tracer is installed (``repro.obs.tracer.
+# trace()``) does the call also become a span; with none, the tracer is
+# never touched.  Span tags: ``step`` = panel index k, ``it`` = the
+# iteration that ran the work, ``depth`` = step − it, the in-flight
+# distance that makes la(d) overlap visible in a timeline.
+# ---------------------------------------------------------------------------
+def _hook(tr, cat, name, thunk, **tags):
+    """Run one engine hook under ``repro.<cat>``; a span too when traced."""
+    with jax.named_scope(f"repro.{cat}"):
+        if tr is None:
+            return thunk()
+        return tr.wrap(cat, name, thunk, **tags)
+
+
+def _epilogue(tr, ops, state, ctx, st, backend, i):
+    """The per-iteration epilogue, scoped only when it does real work."""
+    if ops.update_left is None and ops.commit is None:
+        return state
+    return _hook(tr, "EPI", f"EPI({i})",
+                 lambda: ops._epilogue(state, ctx, st, backend),
+                 step=i, it=i)
+
+
+# ---------------------------------------------------------------------------
+# MTB: PF(k) ; barrier ; TU(k) over the whole trailing matrix (Listing 3).
 # ---------------------------------------------------------------------------
 def _run_mtb(ops, a, b, backend, panel_fn):
     tr = _obs.active()
@@ -235,48 +254,25 @@ def _run_mtb(ops, a, b, backend, panel_fn):
     for i, st in enumerate(panel_steps(n, b)):
         if ops._stop(state, st):
             break
-        if tr is None:
-            state, ctx = ops.factor(state, st, backend, panel_fn)
-        else:
-            state, ctx = tr.wrap(
-                "PF", f"PF({i})",
-                lambda: ops.factor(state, st, backend, panel_fn),
-                step=i, it=i)
+        state, ctx = _hook(tr, "PF", f"PF({i})",
+                           lambda: ops.factor(state, st, backend, panel_fn),
+                           step=i, it=i)
         if ops.swap is not None:
-            if tr is None:
-                state = ops.swap(state, ctx, st, backend)
-            else:
-                state = tr.wrap("SWAP", f"SWAP({i})",
-                                lambda: ops.swap(state, ctx, st, backend),
-                                step=i, it=i)
+            state = _hook(tr, "SWAP", f"SWAP({i})",
+                          lambda: ops.swap(state, ctx, st, backend),
+                          step=i, it=i)
         if ops.update_all is not None:
-            if tr is None:
-                state = ops.update_all(state, ctx, st, backend)
-            else:
-                state = tr.wrap(
-                    "TU", f"TU({i})",
-                    lambda: ops.update_all(state, ctx, st, backend),
-                    step=i, it=i, cols=(0, n))
+            state = _hook(tr, "TU", f"TU({i})",
+                          lambda: ops.update_all(state, ctx, st, backend),
+                          step=i, it=i, cols=(0, n))
             continue
         if st.k_next < n:
-            if tr is None:
-                state = ops.update(state, ctx, st, st.k_next, n, backend)
-            else:
-                state = tr.wrap(
-                    "TU", f"TU({i})",
-                    lambda: ops.update(state, ctx, st, st.k_next, n, backend),
-                    step=i, it=i, cols=(st.k_next, n))
-        state = _epilogue_traced(tr, ops, state, ctx, st, backend, i)
+            state = _hook(
+                tr, "TU", f"TU({i})",
+                lambda: ops.update(state, ctx, st, st.k_next, n, backend),
+                step=i, it=i, cols=(st.k_next, n))
+        state = _epilogue(tr, ops, state, ctx, st, backend, i)
     return ops.finalize(state)
-
-
-def _epilogue_traced(tr, ops, state, ctx, st, backend, i):
-    """The per-iteration epilogue, spanned only when it does real work."""
-    if tr is None or (ops.update_left is None and ops.commit is None):
-        return ops._epilogue(state, ctx, st, backend)
-    return tr.wrap("EPI", f"EPI({i})",
-                   lambda: ops._epilogue(state, ctx, st, backend),
-                   step=i, it=i)
 
 
 # ---------------------------------------------------------------------------
@@ -289,29 +285,18 @@ def _run_rtm(ops, a, b, backend, panel_fn):
     for i, st in enumerate(panel_steps(n, b)):
         if ops._stop(state, st):
             break
-        if tr is None:
-            state, ctx = ops.factor(state, st, backend, panel_fn)
-        else:
-            state, ctx = tr.wrap(
-                "PF", f"PF({i})",
-                lambda: ops.factor(state, st, backend, panel_fn),
-                step=i, it=i)
+        state, ctx = _hook(tr, "PF", f"PF({i})",
+                           lambda: ops.factor(state, st, backend, panel_fn),
+                           step=i, it=i)
         if ops.swap is not None:
-            if tr is None:
-                state = ops.swap(state, ctx, st, backend)
-            else:
-                state = tr.wrap("SWAP", f"SWAP({i})",
-                                lambda: ops.swap(state, ctx, st, backend),
-                                step=i, it=i)
+            state = _hook(tr, "SWAP", f"SWAP({i})",
+                          lambda: ops.swap(state, ctx, st, backend),
+                          step=i, it=i)
         if st.k_next < n:
-            if tr is None:
-                state = ops.tiles(state, ctx, st, backend)
-            else:
-                state = tr.wrap("TU", f"TU({i})",
-                                lambda: ops.tiles(state, ctx, st, backend),
-                                step=i, it=i, tiles=True,
-                                cols=(st.k_next, n))
-        state = _epilogue_traced(tr, ops, state, ctx, st, backend, i)
+            state = _hook(tr, "TU", f"TU({i})",
+                          lambda: ops.tiles(state, ctx, st, backend),
+                          step=i, it=i, tiles=True, cols=(st.k_next, n))
+        state = _epilogue(tr, ops, state, ctx, st, backend, i)
     return ops.finalize(state)
 
 
@@ -328,29 +313,23 @@ def _run_la(ops, a, b, depth, backend, panel_fn, fused_pu):
     # it=-1: it runs ahead of every iteration (nothing to hide under yet).
     ctx = None
     if ops._factorable(state, steps[0]):
-        if tr is None:
-            state, ctx = ops.factor(state, steps[0], backend, panel_fn)
-        else:
-            state, ctx = tr.wrap(
-                "PF", "PF(0)",
-                lambda: ops.factor(state, steps[0], backend, panel_fn),
-                step=0, it=-1, depth=1)
+        state, ctx = _hook(
+            tr, "PF", "PF(0)",
+            lambda: ops.factor(state, steps[0], backend, panel_fn),
+            step=0, it=-1, depth=1)
 
     for i, st in enumerate(steps):
         # Panel-i interchanges, deferred from the iteration that factored it
         # (i−1): applied to every column outside panel i before any
         # iteration-i update touches them.
         if ops.swap is not None:
-            if tr is None:
-                state = ops.swap(state, ctx, st, backend)
-            else:
-                state = tr.wrap("SWAP", f"SWAP({i})",
-                                lambda: ops.swap(state, ctx, st, backend),
-                                step=i, it=i)
+            state = _hook(tr, "SWAP", f"SWAP({i})",
+                          lambda: ops.swap(state, ctx, st, backend),
+                          step=i, it=i)
         if ops._stop(state, st):
             break
         if st.k_next >= n:
-            state = _epilogue_traced(tr, ops, state, ctx, st, backend, i)
+            state = _epilogue(tr, ops, state, ctx, st, backend, i)
             break
 
         # PU chain: narrow updates of the next `dd` panels' columns; PF(i+1)
@@ -367,60 +346,35 @@ def _run_la(ops, a, b, depth, backend, panel_fn, fused_pu):
         nctx = _MISSING
         for j in range(1, dd + 1):
             stj = steps[i + j]
+            if j == 1 and fused_pu is not None and ops.pu is not None:
+                # one fused VMEM kernel does TU^L + PF — a single scope and
+                # span; its PF share is not separable, so overlap
+                # accounting treats it as chain (PU) time.
+                state, nctx = _hook(
+                    tr, "PU", f"PU+PF({i}->{i + 1})",
+                    lambda: ops.pu(state, ctx, st, stj, backend, fused_pu),
+                    step=i, it=i, depth=1, fused=True,
+                    cols=(stj.k, stj.k_next))
+                continue
+            state = _hook(
+                tr, "PU", f"PU({i}->{i + j})",
+                lambda: ops.update(state, ctx, st, stj.k, stj.k_next,
+                                   backend),
+                step=i, it=i, depth=j, cols=(stj.k, stj.k_next))
             if j == 1:
-                if fused_pu is not None and ops.pu is not None:
-                    if tr is None:
-                        state, nctx = ops.pu(state, ctx, st, stj, backend,
-                                             fused_pu)
-                    else:
-                        # one fused VMEM kernel does TU^L + PF — a single
-                        # span; its PF share is not separable, so overlap
-                        # accounting treats it as chain (PU) time.
-                        state, nctx = tr.wrap(
-                            "PU", f"PU+PF({i}->{i + 1})",
-                            lambda: ops.pu(state, ctx, st, stj, backend,
-                                           fused_pu),
-                            step=i, it=i, depth=1, fused=True,
-                            cols=(stj.k, stj.k_next))
-                else:
-                    if tr is None:
-                        state = ops.update(state, ctx, st, stj.k, stj.k_next,
-                                           backend)
-                        state, nctx = ops.factor(state, stj, backend,
-                                                 panel_fn)
-                    else:
-                        state = tr.wrap(
-                            "PU", f"PU({i}->{i + j})",
-                            lambda: ops.update(state, ctx, st, stj.k,
-                                               stj.k_next, backend),
-                            step=i, it=i, depth=j, cols=(stj.k, stj.k_next))
-                        state, nctx = tr.wrap(
-                            "PF", f"PF({i + j})",
-                            lambda: ops.factor(state, stj, backend, panel_fn),
-                            step=i + j, it=i, depth=j)
-            else:
-                if tr is None:
-                    state = ops.update(state, ctx, st, stj.k, stj.k_next,
-                                       backend)
-                else:
-                    state = tr.wrap(
-                        "PU", f"PU({i}->{i + j})",
-                        lambda: ops.update(state, ctx, st, stj.k, stj.k_next,
-                                           backend),
-                        step=i, it=i, depth=j, cols=(stj.k, stj.k_next))
+                state, nctx = _hook(
+                    tr, "PF", f"PF({i + j})",
+                    lambda: ops.factor(state, stj, backend, panel_fn),
+                    step=i + j, it=i, depth=j)
 
         # TU_right(i): the bulk update — data-independent of the PU chain.
         r0 = steps[i + dd].k_next if dd >= 1 else st.k_next
         if r0 < n:
-            if tr is None:
-                state = ops.update(state, ctx, st, r0, n, backend)
-            else:
-                state = tr.wrap(
-                    "TU", f"TU({i})",
-                    lambda: ops.update(state, ctx, st, r0, n, backend),
-                    step=i, it=i, cols=(r0, n), inflight=dd)
+            state = _hook(tr, "TU", f"TU({i})",
+                          lambda: ops.update(state, ctx, st, r0, n, backend),
+                          step=i, it=i, cols=(r0, n), inflight=dd)
 
-        state = _epilogue_traced(tr, ops, state, ctx, st, backend, i)
+        state = _epilogue(tr, ops, state, ctx, st, backend, i)
         if nctx is not _MISSING:
             ctx = nctx
     return ops.finalize(state)

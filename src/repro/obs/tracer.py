@@ -8,12 +8,16 @@ same evidence from our engine: every hook invocation of
 becomes a :class:`Span` tagged with its category (``PF``/``TU``/``PU``/…),
 panel index, owning iteration, and **in-flight depth** — how many
 iterations ahead of its owning iteration a panel was pre-factored, the
-quantity that makes ``la(d)`` overlap visible in the exported timeline.
+quantity that makes ``la(d)`` overlap visible in the profiler's timeline.
 
 Design constraints (the contract the tests pin):
 
-* **Zero dependencies.**  Pure stdlib; ``jax`` is imported lazily and only
-  when a span needs to fence device work.
+* **Zero dependencies.**  Pure stdlib; ``jax`` is imported lazily, when a
+  span needs to fence device work or opens its profiler annotation.
+* **On the profiler's clock.**  Each span is also a
+  ``jax.profiler.TraceAnnotation("repro.<cat>")`` carrying its name, step
+  and depth, so under ``jax.profiler.trace`` the eager engine's, the
+  mesh's and the server's spans land beside the device ops.
 * **Disabled is free and bitwise-invisible.**  No tracer installed ⇒ every
   instrumented site runs its original code path guarded by a single
   ``tracer.active() is None`` predicate — same ops, same order, bitwise
@@ -151,6 +155,17 @@ def _fence(value: Any) -> None:
         pass
 
 
+def _annotation(cat: str, name: str, step: int, depth: int):
+    """``jax.profiler.TraceAnnotation("repro.<cat>")`` for one span, with
+    its name, step and depth as metadata: while a profiler runs, the span
+    lands in its trace on the same clock as the device ops.  Outside a
+    profile it costs one check."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(f"repro.{cat}", name=name,
+                                        step=step, depth=depth)
+
+
 class Tracer:
     """Span recorder with injectable clock and optional metrics registry.
 
@@ -186,17 +201,19 @@ class Tracer:
         hook, no context-manager overhead in the loop body.
         """
         t0 = self.clock()
-        out = thunk()
         meta = dict(meta)
-        if _is_abstract(out):
-            # under jit: fencing is impossible and the timestamps would be
-            # trace-time fabrications — tag the span and warn once instead
-            meta["traced"] = True
-            _note_traced(name)
-        elif self.fence:
-            _fence(out)
-        self.add(Span(cat, name, t0, self.clock(), step=step, it=it,
-                      depth=depth, meta=meta))
+        with _annotation(cat, name, step, depth):
+            out = thunk()
+            if _is_abstract(out):
+                # under jit: fencing is impossible and the timestamps would
+                # be trace-time fabrications — tag the span and warn once
+                meta["traced"] = True
+                _note_traced(name)
+            elif self.fence:
+                _fence(out)
+            t1 = self.clock()
+        self.add(Span(cat, name, t0, t1, step=step, it=it, depth=depth,
+                      meta=meta))
         return out
 
     @contextlib.contextmanager
@@ -206,17 +223,18 @@ class Tracer:
         driver bodies).  ``fence_on`` optionally names the value to fence
         before the end timestamp."""
         t0 = self.clock()
-        try:
-            yield
-        finally:
-            meta = dict(meta)
-            if fence_on is not None and _is_abstract(fence_on):
-                meta["traced"] = True
-                _note_traced(name)
-            elif self.fence and fence_on is not None:
-                _fence(fence_on)
-            self.add(Span(cat, name, t0, self.clock(), step=step, it=it,
-                          depth=depth, meta=meta))
+        with _annotation(cat, name, step, depth):
+            try:
+                yield
+            finally:
+                meta = dict(meta)
+                if fence_on is not None and _is_abstract(fence_on):
+                    meta["traced"] = True
+                    _note_traced(name)
+                elif self.fence and fence_on is not None:
+                    _fence(fence_on)
+                self.add(Span(cat, name, t0, self.clock(), step=step, it=it,
+                              depth=depth, meta=meta))
 
     # -- queries --------------------------------------------------------
     def by_cat(self, cat: str) -> List[Span]:

@@ -8,9 +8,9 @@ Pins the four guarantees the observability layer makes:
   touch the tracer (a raising tracer proves it) and outputs across
   dmf × variant equal the traced outputs bit for bit;
 * tracing **enabled** changes no numerics (same sweep);
-* the export/report/benchmark plumbing round-trips: Chrome-trace JSON
-  schema, BENCH row validation, HLO-accounting fallback warnings, and the
-  serve/tracer shared metrics registry.
+* the report/benchmark plumbing round-trips: BENCH row validation,
+  HLO-accounting fallback warnings, and the serve/tracer shared metrics
+  registry; spans reach the profiler's trace as host annotations.
 """
 import json
 
@@ -21,7 +21,6 @@ import pytest
 from conformance import make_input
 from repro.core.lookahead import get_variant, list_variants
 from repro.obs import Metrics, Span, Tracer, active, trace
-from repro.obs import export as obs_export
 from repro.obs import report as obs_report
 from repro.obs import tracer as obs_tracer
 
@@ -210,38 +209,45 @@ def test_disabled_path_never_calls_the_tracer(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Export: Chrome trace schema + terminal timeline.
+# The profiler's trace: host spans as annotations, kernels as scopes.
 # ---------------------------------------------------------------------------
-def test_chrome_trace_schema(tmp_path):
-    spans = [_syn("PF", 1.0, 2.0, step=0, it=-1, depth=1),
-             _syn("TU", 2.0, 4.0, step=0, it=0)]
-    doc = obs_export.chrome_trace(spans, label="unit")
-    doc = json.loads(json.dumps(doc))          # must be JSON-serializable
-    events = doc["traceEvents"]
-    meta = [e for e in events if e["ph"] == "M"]
-    xs = [e for e in events if e["ph"] == "X"]
-    assert any(e["name"] == "process_name"
-               and e["args"]["name"] == "unit" for e in meta)
-    assert {e["name"] for e in meta if e["name"] == "thread_name"} \
-        == {"thread_name"}
-    assert len(xs) == 2
-    pf = next(e for e in xs if e["cat"] == "PF")
-    tu = next(e for e in xs if e["cat"] == "TU")
-    assert pf["tid"] != tu["tid"]              # panel and update lanes
-    assert pf["ts"] == 0.0 and pf["dur"] == pytest.approx(1e6)  # µs
-    assert pf["args"]["depth"] == 1 and tu["args"]["iter"] == 0
+def test_eager_spans_reach_the_profiler_trace(tmp_path):
+    import glob
 
-    path = obs_export.write_chrome_trace(str(tmp_path / "t.json"), spans)
-    with open(path) as f:
-        assert json.load(f)["traceEvents"]
+    from jax.profiler import ProfileData
+    from repro.solve import drivers
+
+    a = make_input("lu", 64, 64, seed=3, dtype="float32")
+    with jax.profiler.trace(str(tmp_path)):
+        with trace() as tr:
+            jax.block_until_ready(drivers.lu_factor(a, 16))
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("repro.")]
+    pf = [ev for ev in events if ev.name == "repro.PF"]
+    assert len(pf) == len(tr.by_cat("PF")) == 4
+    assert {dict(ev.stats)["name"] for ev in pf} \
+        == {s.name for s in tr.by_cat("PF")}
+    assert any(ev.name == "repro.drive" for ev in events)
 
 
-def test_render_timeline():
-    spans = [_syn("PF", 0.0, 1.0, step=0), _syn("TU", 1.0, 2.0, step=0)]
-    out = obs_export.render_timeline(spans, width=20)
-    assert "panel (PF)" in out and "update (TU)" in out
-    assert "P" in out and "U" in out
-    assert obs_export.render_timeline([]) == "(no spans)"
+def test_backend_kernels_are_scoped_and_backends_stay_equal():
+    from repro.core import backend as be
+
+    jnp_be = be.Backend(name="jnp", gemm=be.gemm_jnp, trsm=be.trsm_jnp)
+    assert jnp_be == be.JNP_BACKEND and hash(jnp_be) == hash(be.JNP_BACKEND)
+    assert jnp_be.gemm.scope == be.GEMM_SCOPE and jnp_be.gemm.fn is be.gemm_jnp
+    assert jnp_be.trsm.scope == be.TRSM_SCOPE
+    import dataclasses
+    again = dataclasses.replace(jnp_be, name="jnp")
+    assert again.gemm == jnp_be.gemm and again.gemm.fn is be.gemm_jnp
+    text = jax.jit(jnp_be.update).lower(
+        np.ones((8, 4), np.float32), np.ones((8, 2), np.float32),
+        np.ones((2, 4), np.float32)).as_text(debug_info=True)
+    assert "repro.gemm" in text
 
 
 # ---------------------------------------------------------------------------

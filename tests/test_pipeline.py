@@ -250,3 +250,39 @@ def test_make_variant_builds_standalone_drivers():
     la = pipeline.make_variant(L.LU_OPS, "la")
     assert pipeline.supports_depth(la)
     _assert_tree_equal(L.lu_lookahead(a, 16, depth=2), la(a, 16, depth=2))
+
+
+# ---------------------------------------------------------------------------
+# Device-visible names (DESIGN.md §14): each hook runs under a
+# ``repro.<CAT>`` scope and each backend kernel under ``repro.gemm`` /
+# ``repro.trsm``.  Scopes are HLO metadata: the compiled factorization
+# still equals the legacy loop bit for bit.
+# ---------------------------------------------------------------------------
+_SCOPED_PHASES = {"mtb": ("PF", "TU"), "rtm": ("PF", "TU"),
+                  "la": ("PF", "PU", "TU")}
+
+
+@pytest.mark.parametrize("dmf", ["lu", "cholesky"])
+@pytest.mark.parametrize("variant", sorted(_SCOPED_PHASES))
+def test_compiled_factor_carries_scopes_and_keeps_bits(dmf, variant):
+    from repro.solve import drivers
+
+    gen, legacy_fn, _ = CASES[(dmf, variant)]
+    a = gen(64, seed=9, dtype=np.float32)
+    if dmf == "lu":
+        def fn(x):
+            f = drivers.lu_factor(x, 16, variant=variant)
+            return f.lu, f.ipiv
+        phases = _SCOPED_PHASES[variant] + ("SWAP",)
+    else:
+        def fn(x):
+            return drivers.cholesky_factor(x, 16, variant=variant).l
+        phases = _SCOPED_PHASES[variant]
+    compiled = jax.jit(fn).lower(a).compile()
+    text = compiled.as_text()
+    for scope in [f"repro.{cat}" for cat in phases] + ["repro.gemm",
+                                                       "repro.trsm"]:
+        assert f"/{scope}/" in text, scope
+    if dmf == "cholesky":
+        assert "/repro.PF/repro.trsm/" in text      # the panel's TRSM
+    _assert_tree_equal(jax.jit(lambda x: legacy_fn(x, 16))(a), compiled(a))
