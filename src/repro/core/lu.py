@@ -90,15 +90,24 @@ def lu_unblocked(panel: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 # Row interchanges (LASWP analogue).
 # ---------------------------------------------------------------------------
 def laswp(a: jnp.ndarray, piv: jnp.ndarray, offset: int = 0) -> jnp.ndarray:
-    """Apply the swap sequence ``row offset+j <-> row offset+piv[j]``."""
+    """Apply the swap sequence ``row offset+j <-> row offset+piv[j]``.
 
-    def body(j, a):
-        p = piv[j] + offset
-        q = j + offset
-        rq, rp = a[q], a[p]
-        return a.at[q].set(rp).at[p].set(rq)
+    The sequence is composed into one permutation first, on integers only;
+    the rows it moves are then copied with one gather and one scatter.
+    """
+    rows, src = _moved_rows(piv, a.shape[0] - offset)
+    return a.at[rows + offset].set(a[src + offset])
 
-    return lax.fori_loop(0, piv.shape[0], body, a)
+
+def _moved_rows(piv: jnp.ndarray, m: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The rows a swap sequence on ``m`` rows can move, and where each one's
+    new content comes from: ``P·A`` and ``A`` differ only in rows
+    ``[0, bk) ∪ piv``, and row ``rows[i]`` of ``P·A`` is row ``src[i]`` of
+    ``A``.  Repeated entries of ``rows`` carry the same ``src``, so a scatter
+    through them writes identical values whatever its order."""
+    piv = piv.astype(jnp.int32)
+    rows = jnp.concatenate([jnp.arange(piv.shape[0], dtype=jnp.int32), piv])
+    return rows, permutation_from_pivots(piv, m)[rows]
 
 
 def permutation_from_pivots(piv: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -109,7 +118,8 @@ def permutation_from_pivots(piv: jnp.ndarray, n: int) -> jnp.ndarray:
         pj, pp = perm[j], perm[p]
         return perm.at[j].set(pp).at[p].set(pj)
 
-    return lax.fori_loop(0, piv.shape[0], body, jnp.arange(n))
+    return lax.fori_loop(0, piv.shape[0], body,
+                         jnp.arange(n, dtype=piv.dtype))
 
 
 def unpack_lu(lu: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -144,14 +154,16 @@ def _factor(state, st, backend, panel_fn):
 
 def _swap(state, ctx, st, backend):
     # Interchanges of panel k applied to every column outside the panel —
-    # eager under mtb/rtm, deferred one iteration under la (Listing 5).
+    # eager under mtb/rtm, deferred one iteration under la (Listing 5).  One
+    # gather and one scatter of the moved full rows; the panel's columns
+    # keep what PF wrote, as its rows were swapped inside PF.
     a, ipiv = state
-    k = st.k
-    if k > 0:
-        a = a.at[:, :k].set(laswp(a[:, :k], ctx.piv, offset=k))
-    if st.k_next < a.shape[1]:
-        a = a.at[:, st.k_next :].set(
-            laswp(a[:, st.k_next :], ctx.piv, offset=k))
+    k, k_next = st.k, st.k_next
+    rows, src = _moved_rows(ctx.piv, a.shape[0] - k)
+    rows, src = rows + k, src + k
+    cols = jnp.arange(a.shape[1])
+    in_panel = (cols >= k) & (cols < k_next)
+    a = a.at[rows].set(jnp.where(in_panel, a[rows], a[src]))
     return (a, ipiv)
 
 

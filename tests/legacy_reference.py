@@ -9,7 +9,10 @@ machine's float behaviour, so instead this module preserves the removed loop
 bodies **unchanged** (same slicing, same op order), importing the panel /
 update building blocks from the live modules — the building blocks were not
 touched by the refactor, so any test divergence isolates to the loop
-restructuring under test.
+restructuring under test.  The one exception is ``laswp``: the live one
+applies a panel's interchanges as a single gather and scatter, so the
+sequential per-pivot loop it replaced is frozen here too (copied from commit
+857133f), and the engine's row interchanges are checked against it.
 
 Copied from commit c8308c9 (PR 2 head).  Do not "improve" this file: it is a
 historical artifact by design.
@@ -17,20 +20,33 @@ historical artifact by design.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.backend import JNP_BACKEND
 from repro.core.blocking import panel_steps, split_trailing
 from repro.core.cholesky import cholesky_panel
 from repro.core.gauss_jordan import gj_inverse_unblocked
 from repro.core.ldlt import ldlt_panel
-from repro.core.lu import laswp, lu_unblocked
+from repro.core.lu import lu_unblocked
 from repro.core.qr import (_factor_panel, _Panel, apply_qt_blocked,
                            build_t_matrix, unpack_v)
 
 
 # ---------------------------------------------------------------------------
-# LU — verbatim pre-refactor lu_blocked / lu_tiled / lu_lookahead.
+# LU — verbatim pre-refactor laswp / lu_blocked / lu_tiled / lu_lookahead.
 # ---------------------------------------------------------------------------
+def laswp(a, piv, offset=0):
+    """Apply the swap sequence ``row offset+j <-> row offset+piv[j]``."""
+
+    def body(j, a):
+        p = piv[j] + offset
+        q = j + offset
+        rq, rp = a[q], a[p]
+        return a.at[q].set(rp).at[p].set(rq)
+
+    return lax.fori_loop(0, piv.shape[0], body, a)
+
+
 def lu_blocked(a, b=128, *, backend=JNP_BACKEND, panel_fn=None):
     n = a.shape[0]
     panel_fn = panel_fn or lu_unblocked
