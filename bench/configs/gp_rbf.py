@@ -113,3 +113,14 @@ class Cell:
         return {"alpha_backward_error_eps": plain.solve_error(k, alpha, y),
                 "logdet_rel_error_eps": rel if np.isfinite(rel)
                 else float("inf")}
+
+    def plant(self, kind: str, data, ops, out):
+        """``out`` with a fault planted, for the CPU tests (never timed):
+        ``state_unchanged`` hands back y as α, ``answer_altered`` scales
+        α's first entry by 1.01 and log det by 1 + 1e-4."""
+        alpha, logdet, *rest = out
+        if kind == "state_unchanged":
+            return (data[1], logdet, *rest)
+        if kind == "answer_altered":
+            return (alpha.at[0].multiply(1.01), logdet * (1 + 1e-4), *rest)
+        raise ValueError(f"no planted fault {kind!r}")

@@ -55,3 +55,13 @@ class Cell:
     def check(self, data, ops, out) -> dict[str, float]:
         a, b = jax.device_get(ops)
         return {"backward_error_eps": plain.solve_error(a, out, b)}
+
+    def plant(self, kind: str, data, ops, out):
+        """``out`` with a fault planted, for the CPU tests (never timed):
+        ``state_unchanged`` hands back b as x, ``answer_altered`` scales
+        x's first row by 1.01."""
+        if kind == "state_unchanged":
+            return ops[1]
+        if kind == "answer_altered":
+            return out.at[0].multiply(1.01)
+        raise ValueError(f"no planted fault {kind!r}")

@@ -10,12 +10,21 @@ metric is found by its name in ``BENCHMARK.json``:
   ``inputs(key, i, data)`` (step i's operands, drawn on the device),
   ``step(data, ops)`` (the timed program, with its ``bench.*`` scopes),
   ``control(data, ops, precision)`` (the plain reference in the program's
-  place) and ``check(data, ops, out)`` (the numbers compared with
-  ``limits``);
+  place), ``check(data, ops, out)`` (the numbers compared with
+  ``limits``) and ``plant(kind, data, ops, out)`` (``out`` with a fault
+  planted, ``"state_unchanged"`` or ``"answer_altered"``, for the CPU
+  tests); ``bench/configs/<config>.cpu.json`` — the sizes and limits the
+  CPU tests and rehearsals run at (:func:`cpu_overrides`), never read by a
+  benchmark run;
 * ``bench/traffic/<traffic>.json`` — the mix's parameters, read by this
   module's closed loop and by the cell;
 * ``bench/metrics/<metric>.py`` — ``read(summary)`` for one per-layer
-  metric, returning a number or None.
+  metric, returning a number or None.  The summary
+  (:class:`bench.trace.Summary`) carries the trace's numbers, the phase
+  and kernel split (:mod:`bench.phases`), the configuration as run, the
+  traffic, the device's peaks and the configuration's module; a roofline
+  share comes from :func:`bench.roofline.share`, with the kernel's
+  operations and bytes counted by a function of that module.
 """
 from __future__ import annotations
 
@@ -111,6 +120,17 @@ def resolve(spec: dict, workload: str, root: str = ROOT) -> Resolved:
     return Resolved(w, cfg, cfg_file, mod, traffic, e2e, per_layer)
 
 
+def cpu_overrides(workload: str, root: str = ROOT) -> dict:
+    """The overrides of ``bench/configs/<config>.cpu.json`` (its ``why``
+    left out): the sizes and limits the CPU tests and rehearsals use."""
+    res = resolve(load_spec(root), workload, root)
+    path = os.path.join(root, res.config["file"])[: -len(".json")]
+    with open(path + ".cpu.json") as f:
+        over = json.load(f)
+    over.pop("why", None)
+    return over
+
+
 # ---------------------------------------------------------------------------
 # Device, peaks, seeds.
 # ---------------------------------------------------------------------------
@@ -190,7 +210,8 @@ def _compiles() -> _CompileCounter:
 
 def build(workload: str, overrides: Optional[dict] = None,
           require_chip: bool = True, root: str = ROOT):
-    """``(resolved, devices, cell)`` for one workload.
+    """``(resolved, devices, config, cell)`` for one workload; ``config``
+    is the configuration as run.
 
     ``overrides`` replaces keys of the configuration file (sizes, limits);
     the CPU tests and rehearsals use it, a benchmark run never does.
@@ -198,7 +219,7 @@ def build(workload: str, overrides: Optional[dict] = None,
     res = resolve(load_spec(root), workload, root)
     devs = devices(res.workload["chips"], require_chip)
     cfg = {**res.config_file, **(overrides or {})}
-    return res, devs, res.config_module.Cell(cfg, res.traffic)
+    return res, devs, cfg, res.config_module.Cell(cfg, res.traffic)
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -209,18 +230,18 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     """One run of one cell; the result line.  See ``bench/run.py``.
 
     ``overrides`` (see :func:`build`) and ``wrap_step`` (a function of the
-    cell's step returning the step to time) exist for the CPU tests, which
-    run the harness at tiny sizes and with faults planted.
+    cell returning the step to time) exist for the CPU tests, which run the
+    harness at tiny sizes and with faults planted.
     """
     import jax
 
-    res, devs, cell = build(workload, overrides, require_chip, root)
+    res, devs, cfg, cell = build(workload, overrides, require_chip, root)
     key = seed_key(seed)
     counter = _compiles()
 
     data = cell.prepare(key)
     inputs = jax.jit(cell.inputs).lower(key, np.int32(0), data).compile()
-    step_fn = cell.step if wrap_step is None else wrap_step(cell.step)
+    step_fn = cell.step if wrap_step is None else wrap_step(cell)
     ops = inputs(key, np.int32(0), data)
     compiled = jax.jit(step_fn).lower(data, ops).compile()
     jax.block_until_ready(compiled(data, ops))           # warm-up step
@@ -266,7 +287,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     metrics, breakdown = {}, None
     if trace:
         summary = _summarize([inputs.as_text(), compiled.as_text()], steps,
-                             log)
+                             log, config=cfg, traffic=res.traffic,
+                             peaks=peaks(device["kind"], root),
+                             module=res.config_module)
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         for m, read in res.per_layer:
@@ -324,26 +347,38 @@ def _check(cell, key, data, inputs, outs, sample):
             sample)
 
 
-def _summarize(hlo_texts: list[str], steps: int, log):
-    from bench import trace as tr
-
+def _summarize(hlo_texts: list[str], steps: int, log, **context):
     files = glob.glob(os.path.join(TRACE_DIR, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if len(files) != 1:
         raise RuntimeError(f"expected one trace file under {TRACE_DIR}, "
                            f"found {len(files)}")
     try:
-        ops, spans, runs = tr.read_xplane(
-            files[0], {tr.module_name(t): t for t in hlo_texts})
+        return summarize_file(files[0], hlo_texts, steps, log=log, **context)
     finally:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+
+
+def summarize_file(source, hlo_texts: list[str], steps: int, *,
+                   log=sys.stderr, **context):
+    """The :class:`bench.trace.Summary` the readers get, from one
+    ``.xplane.pb`` (a path or the file's bytes) of a traced window of
+    ``steps`` steps: the trace's numbers, its phase and kernel split, and
+    the run's ``context`` (``config``, ``traffic``, ``peaks``, ``module``)
+    as given.  ``hlo_texts`` are the compiled programs, the step program
+    last."""
+    from bench import phases
+    from bench import trace as tr
+
+    programs = {tr.module_name(t): t for t in hlo_texts}
+    step_module = tr.module_name(hlo_texts[-1])
+    ops, spans, runs = tr.read_xplane(source, programs)
     windows = [s for s in spans if s.name == "bench.window"]
     if len(windows) != 1:
         raise RuntimeError(f"expected one bench.window span, found "
                            f"{len(windows)}")
     summary = tr.summarize(ops, spans, window=windows[0], steps=steps,
-                           module_runs=runs,
-                           step_module=tr.module_name(hlo_texts[-1]))
+                           module_runs=runs, step_module=step_module)
     if summary.busy_s <= 0:
         raise RuntimeError("the trace holds no device op inside the window")
     if summary.step_runs != steps:
@@ -357,7 +392,11 @@ def _summarize(hlo_texts: list[str], steps: int, log):
     print(f"bench: trace {len(ops)} device ops; per-scope device s "
           f"{json.dumps(summary.scope_s)}; charged by the neighbour rule "
           f"{summary.inherited_s!r} s", file=log)
-    return summary
+    t = time.perf_counter()
+    split = phases.read(source, programs, step_module)
+    print(f"bench: phase split {json.dumps(split.per_step())} s a step; "
+          f"read in {time.perf_counter() - t!r} s", file=log)
+    return dataclasses.replace(summary, split=split, **context)
 
 
 def print_line(line: dict, log=sys.stderr) -> None:

@@ -36,14 +36,15 @@ def _seeds(text: str) -> list[int]:
 
 
 def readings(workload: str, seeds, steps: int, controls, control_seeds,
-             overrides=None, require_chip: bool = True, emit=print):
+             overrides=None, require_chip: bool = True, emit=print,
+             root: str = ROOT):
     """Yield one dict per (who, seed, step) with the check's numbers."""
     import jax
     import numpy as np
 
     from bench import harness
 
-    _, _, cell = harness.build(workload, overrides, require_chip)
+    _, _, _, cell = harness.build(workload, overrides, require_chip, root)
     inputs = jax.jit(cell.inputs)
     runs = [("program", jax.jit(cell.step), seeds)]
     runs += [(f"control:{p}",

@@ -35,7 +35,7 @@ def main(argv=None) -> int:
 
     from bench import harness, trace
 
-    _, _, cell = harness.build(
+    _, _, _, cell = harness.build(
         args.workload, {k: int(v) for k, v in (s.split("=") for s in args.set)})
     key = harness.seed_key(1)
     data = cell.prepare(key)

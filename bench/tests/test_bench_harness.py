@@ -19,30 +19,19 @@ from bench import harness, readings
 ROOT = harness.ROOT
 SPEC = harness.load_spec()
 CELLS = [w["name"] for w in SPEC["workloads"]]
-#: Sizes small enough for the CPU, every other number the cell's own but
-#: the limits.  The cells' limits are set from chip readings at n=8192
-#: (PERF.md §2); these are set the same way from CPU readings at n=256,
-#: nb=64 (program / `high` control, 4 and 3 seeds): hpl_lu
-#: 0.86–1.59 / 22.7–38.1; gp_rbf α 0.029–0.058 / 0.47–0.74, log det
-#: 0.14–1.48 / 149–163.
-TINY = {
-    "hpl_lu": {"n": 256, "nb": 64, "limits": {"backward_error_eps": 8.0}},
-    "gp_rbf": {"n": 256, "nb": 64,
-               "limits": {"alpha_backward_error_eps": 0.2,
-                          "logdet_rel_error_eps": 20.0}},
-}
+#: The kinds of fault each configuration's ``Cell.plant`` plants.
+FAULTS = ["state_unchanged", "answer_altered"]
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
-def _tiny(cell: str) -> dict:
-    return TINY[harness.resolve(SPEC, cell).config["name"]]
-
-
 def _run(cell: str, seed: int = 2**33 + 7, **kw) -> dict:
+    """One untraced run at the configuration's CPU sizes
+    (``bench/configs/<config>.cpu.json``)."""
     return harness.run(cell, seed, 0.2, False, t0=time.perf_counter(),
-                       require_chip=False, overrides=_tiny(cell), **kw)
+                       require_chip=False,
+                       overrides=harness.cpu_overrides(cell), **kw)
 
 
 def test_spec_has_the_contract_keys_and_names():
@@ -75,6 +64,14 @@ def test_cell_resolves_by_name(cell):
     assert res.per_layer, "every cell reports a per-layer metric"
     for path in (res.config["file"], f"bench/traffic/{res.workload['traffic']}.json"):
         assert os.path.exists(os.path.join(ROOT, path))
+    # the CPU sizes: a file of their own beside the configuration's, which
+    # changes only keys the configuration has and names its every limit
+    assert os.path.exists(os.path.join(
+        ROOT, res.config["file"][: -len(".json")] + ".cpu.json"))
+    over = harness.cpu_overrides(cell)
+    assert set(over) <= set(res.config_file) - {"name", "reduced"}
+    assert set(over.get("limits", res.config_file["limits"])) \
+        == set(res.config_file["limits"])
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -86,7 +83,7 @@ def test_tiny_run_is_correct(cell):
     assert list(line)[-1] == "checks"
     assert set(line["checks"]) == set(
         harness.resolve(SPEC, cell).config_file["limits"])
-    assert all(c["limit"] == _tiny(cell)["limits"][name]
+    assert all(c["limit"] == harness.cpu_overrides(cell)["limits"][name]
                for name, c in line["checks"].items())
 
 
@@ -95,10 +92,11 @@ def test_tiny_run_is_correct(cell):
 def test_control_fails_the_check(cell, precision):
     """The plain reference in the program's place, at the precision below
     the configuration's (``high``) and at bf16, fails a limit."""
+    over = harness.cpu_overrides(cell)
     rows = []
-    readings.readings(cell, [11], 1, [precision], [11], _tiny(cell),
+    readings.readings(cell, [11], 1, [precision], [11], over,
                       require_chip=False, emit=rows.append)
-    limits = _tiny(cell)["limits"]
+    limits = over["limits"]
     prog = [r for r in rows if r["who"] == "program"]
     ctrl = [r for r in rows if r["who"] != "program"]
     assert all(r[k] <= lim for r in prog for k, lim in limits.items())
@@ -108,42 +106,30 @@ def test_control_fails_the_check(cell, precision):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_program_on_bf16_operands_fails_the_check(cell):
-    def wrap(step):
+    def wrap(c):
         def bf16_step(data, ops):
-            out = step(data, jax.tree.map(lambda a: a.astype(jnp.bfloat16),
-                                          ops))
+            out = c.step(data, jax.tree.map(
+                lambda a: a.astype(jnp.bfloat16), ops))
             return jax.tree.map(lambda a: a.astype(jnp.float32), out)
         return bf16_step
 
     assert _run(cell, wrap_step=wrap)["correct"] is False
 
 
-def _unchanged(step):
-    """A step that hands back its right-hand side as the answer."""
-    def run(data, ops):
-        out = step(data, ops)
-        if isinstance(out, tuple):       # gp_rbf: (α, log det, log lik)
-            return (data[1], *out[1:])
-        return ops[1]                    # hpl_lu: x = b
-    return run
-
-
-def _altered(step):
-    """A step whose first answer is altered where it is produced."""
-    def run(data, ops):
-        out = step(data, ops)
-        if isinstance(out, tuple):
-            return (out[0].at[0].multiply(1.01), out[1] * (1 + 1e-4),
-                    *out[2:])
-        return out.at[0].multiply(1.01)
-    return run
+def planted(fault: str):
+    """``wrap_step`` for :func:`harness.run`: the cell's step with the
+    cell's own ``plant(fault, ...)`` applied to its output."""
+    def wrap(c):
+        def faulty_step(data, ops):
+            return c.plant(fault, data, ops, c.step(data, ops))
+        return faulty_step
+    return wrap
 
 
 @pytest.mark.parametrize("cell", CELLS)
-@pytest.mark.parametrize("fault", [_unchanged, _altered],
-                         ids=["state_unchanged", "answer_altered"])
+@pytest.mark.parametrize("fault", FAULTS)
 def test_planted_fault_fails_the_check(cell, fault):
-    assert _run(cell, wrap_step=fault)["correct"] is False
+    assert _run(cell, wrap_step=planted(fault))["correct"] is False
 
 
 def test_run_without_a_chip_prints_no_result():

@@ -20,7 +20,10 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Iterable, Optional
+from typing import TYPE_CHECKING, Iterable, Optional
+
+if TYPE_CHECKING:
+    from bench.phases import Split
 
 #: Line of a device plane that holds one event per executed HLO op.
 OPS_LINE = "XLA Ops"
@@ -116,6 +119,13 @@ class Summary:
     inherited_s: float         # part of scope_s charged by the neighbour rule
     top_ops: list[tuple[str, float]]   # (scope/op kind, self seconds)
     idle_gaps: list[tuple[str, float]]
+    # Set by the harness for a traced run, not by :func:`summarize`:
+    split: Optional[Split] = None      # bench/phases.py's phase/kernel split
+    config: Optional[dict] = None      # the configuration as run
+    traffic: Optional[dict] = None     # bench/traffic/<traffic>.json
+    peaks: Optional[dict] = None       # the device's row of bench/peaks.json
+    module: object = None              # bench/configs/<config>.py, which
+    #                                    counts its kernels' ops and bytes
 
 
 def module_name(text: str) -> str:
